@@ -18,6 +18,8 @@ record carries run metadata -- ``seed`` (``--seed N``, default 0, offsets
 every driver's rng coherently), ``git_sha``, ``backend`` (the resolved
 ``REPRO_LSM_BACKEND``) and ``medium`` (the storage medium the row ran
 on) -- so rows from different machines/checkouts stay attributable.
+JAX's persistent compilation cache lives in ``$JAX_COMPILATION_CACHE_DIR``
+when set, else in ``.jax_cache/`` at the repository root.
 """
 from __future__ import annotations
 
@@ -76,6 +78,8 @@ def main() -> None:
                    fig13_secondary, fig14_tpcc, fig15_tuner_ycsb,
                    fig16_tuner_accuracy, fig17_tuner_responsiveness,
                    kv_serving, recovery)
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache(os.path.join(os.path.dirname(__file__), os.pardir))
     full = "--full" in sys.argv
     smoke = "--smoke" in sys.argv
     json_out = "--json" in sys.argv

@@ -1,8 +1,9 @@
 """Pallas execution backend: routes the engine's primitives through the
 TPU kernels in ``repro.kernels.{merge,bloom}``.
 
-Runs in interpret mode on CPU (functional parity, no TPU required) and
-compiled on TPU. All entry points bucket their operand sizes to powers of
+Kernels run compiled when the backend's arrays land on a TPU and in
+interpret mode anywhere else (``PallasBackend.interpret`` and ``.device``
+say which). All entry points bucket their operand sizes to powers of
 two (sentinel padding) so the jitted kernels compile once per size bucket
 instead of once per exact run length.
 
@@ -45,8 +46,7 @@ class PallasBackend(ExecutionBackend):
     name = "pallas"
 
     def __init__(self, *, interpret: bool | None = None,
-                 merge_tile: int = 512, k_hashes: int = BLOOM_K_HASHES,
-                 fused_wmax: int = 1024):
+                 k_hashes: int = BLOOM_K_HASHES, fused_wmax: int = 1024):
         super().__init__()
         import jax
         import jax.numpy as jnp
@@ -56,17 +56,27 @@ class PallasBackend(ExecutionBackend):
         self._bloom_ops = bloom_ops
         self._merge_ops = merge_ops
         self._jnp = jnp
+        # The device every operand is placed on decides how the kernels
+        # run: compiled on a TPU, interpreted anywhere else. Asking for
+        # compiled kernels off a TPU is an error, never a quiet fallback.
+        self.device = next(iter(jnp.zeros(()).devices()))
+        on_tpu = self.device.platform == "tpu"
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = not on_tpu
+        elif not interpret and not on_tpu:
+            raise ValueError(
+                f"compiled Pallas kernels need a TPU, but arrays land on "
+                f"{self.device.platform!r}; pass interpret=True or None")
         self.interpret = interpret
-        self.merge_tile = merge_tile
         self.k_hashes = k_hashes
         # Widest per-table filter (columns) the fused probe will take
         # resident: bounds the kernel's one-hot working set to VMEM scale.
         self.fused_wmax = fused_wmax
         self._fallback = NumpyBackend(k_hashes=k_hashes)
         self._searchsorted = jax.jit(lambda a, v: jnp.searchsorted(a, v))
-        self.fallback_calls = 0     # out-of-int32-domain merges/probes
+        # Calls sent off the device path: out-of-int32-domain operands,
+        # numpy-built filters, and tiers too wide for the fused probe.
+        self.fallback_calls = 0
 
     # -- merge ---------------------------------------------------------------
     def merge_runs(self, runs):
@@ -80,7 +90,7 @@ class PallasBackend(ExecutionBackend):
         self._note_jit("merge",
                        tuple(next_pow2(len(k)) for k, _ in runs))
         keys, vals = self._merge_ops.merge_runs_device(
-            runs, tile=self.merge_tile, interpret=self.interpret)
+            runs, interpret=self.interpret)
         return keys.astype(np.int64), vals.astype(np.int64)
 
     # -- write ingest --------------------------------------------------------
@@ -107,7 +117,7 @@ class PallasBackend(ExecutionBackend):
         self._note_jit("ingest", next_pow2(h), next_pow2(n - h))
         ks, src = self._merge_ops.ingest_run(
             keys[order].astype(np.int32), order.astype(np.int32),
-            tile=self.merge_tile, interpret=self.interpret)
+            interpret=self.interpret)
         src = src.astype(np.int64)
         return ks.astype(np.int64), vals[src], src
 
@@ -202,6 +212,7 @@ class PallasBackend(ExecutionBackend):
             filts.append(f)                      # bool [128, W_t]
         wmax = max(f.shape[1] for f in filts)
         if wmax > self.fused_wmax:
+            self.fallback_calls += 1
             return None
         fstack = np.zeros((len(tables) * 128, wmax), bool)
         for i, f in enumerate(filts):
@@ -279,6 +290,7 @@ class PallasBackend(ExecutionBackend):
             filts.append(f)                      # bool [128, W_t]
         wmax = max((f.shape[1] for f in filts), default=1)
         if wmax > self.fused_wmax:
+            self.fallback_calls += 1
             return None
         fstack = np.zeros((len(tables) * 128, wmax), bool)
         for i, f in enumerate(filts):

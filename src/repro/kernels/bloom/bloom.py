@@ -2,10 +2,12 @@
 
 TPU adaptation: no scatter/gather by data-dependent addresses (that is a
 CUDA idiom). Both directions run through MXU one-hot matmuls over the
-filter's factorized [128 rows x W cols] layout:
+filter's factorized [128 rows x W cols] layout, one hash at a time, with
+keys as a lane-major [1, K] row and the one-hots transposed ([128, K] row
+one-hot R, [W, K] column one-hot C):
 
-  build:  counts += onehot_rows^T @ onehot_cols      (per key-tile)
-  probe:  rows = onehot_rows @ filter ; value = sum(rows * onehot_cols)
+  build:  counts += R @ C^T                          (per key-tile, hash)
+  probe:  cols = filter @ C ; value = sum(cols * R, axis=0)
 
 The filter stays resident in VMEM across grid steps (accumulator pattern:
 initialized at step 0, revisited by every key tile).
@@ -21,20 +23,38 @@ from jax.experimental import pallas as pl
 from .ref import C1, C2
 
 
-def _hash_onehots(keys, n_slots, w, k_hashes):
-    """Per key and hash j: row/col one-hots. keys [K] -> ([K*k,128],[K*k,W])."""
+def _hash_onehots(keys, n_slots, w, k_hashes, cols):
+    """Per hash j, the transposed row/col one-hots of every key's slot:
+    keys [1, K] -> k_hashes pairs ([128, K], [cols, K]) f32.
+
+    Keys stay a lane-major row and each hash is its own [1, K] slot row,
+    so no [K, k] -> [K*k] reshape reaches the TPU lowering. ``n_slots``
+    and ``w`` are static ints or per-query [1, K] rows; the double hash
+    is the reference's int32 arithmetic either way."""
     h1 = (keys * C1) % n_slots
     h2 = ((keys * C2) | 1) % n_slots
-    j = jax.lax.broadcasted_iota(jnp.int32, (keys.shape[0], k_hashes), 1)
-    slots = (h1[:, None] + j * h2[:, None]) % n_slots            # [K, k]
-    slots = slots.reshape(-1)                                    # [K*k]
-    row = slots // w
-    col = slots % w
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (slots.shape[0], 128), 1)
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (slots.shape[0], w), 1)
-    oh_r = (row[:, None] == r_iota).astype(jnp.float32)
-    oh_c = (col[:, None] == c_iota).astype(jnp.float32)
-    return oh_r, oh_c
+    k = keys.shape[-1]
+    r_iota = jax.lax.broadcasted_iota(jnp.int32, (128, k), 0)
+    c_iota = jax.lax.broadcasted_iota(jnp.int32, (cols, k), 0)
+    for j in range(k_hashes):
+        slots = (h1 + j * h2) % n_slots                          # [1, K]
+        yield ((slots // w == r_iota).astype(jnp.float32),
+               (slots % w == c_iota).astype(jnp.float32))
+
+
+def _member(filt, keys, n_slots, w, k_hashes):
+    """keys [1, K] against one [128, cols] filter block -> bool [1, K]:
+    per hash, the filter column at each key's slot (an MXU one-hot
+    matmul), reduced against the row one-hot."""
+    filt = filt.astype(jnp.float32)
+    member = None
+    for oh_r, oh_c in _hash_onehots(keys, n_slots, w, k_hashes,
+                                    filt.shape[1]):
+        cols = jax.lax.dot(filt, oh_c,
+                           precision=jax.lax.Precision.HIGHEST)  # [128, K]
+        hit = jnp.sum(cols * oh_r, axis=0, keepdims=True) > 0
+        member = hit if member is None else member & hit
+    return member
 
 
 def _build_kernel(keys_ref, filt_ref, *, n_slots, w, k_hashes):
@@ -42,21 +62,17 @@ def _build_kernel(keys_ref, filt_ref, *, n_slots, w, k_hashes):
     def _init():
         filt_ref[...] = jnp.zeros_like(filt_ref)
 
-    keys = keys_ref[...].reshape(-1)
-    oh_r, oh_c = _hash_onehots(keys, n_slots, w, k_hashes)
-    counts = jax.lax.dot(oh_r.T, oh_c,
-                         precision=jax.lax.Precision.HIGHEST)    # [128, W]
+    counts = jnp.zeros(filt_ref.shape, jnp.float32)
+    for oh_r, oh_c in _hash_onehots(keys_ref[...], n_slots, w, k_hashes, w):
+        counts += jax.lax.dot_general(                           # [128, W]
+            oh_r, oh_c, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST)
     filt_ref[...] += counts.astype(jnp.int32)
 
 
 def _probe_kernel(keys_ref, filt_ref, out_ref, *, n_slots, w, k_hashes):
-    keys = keys_ref[...].reshape(-1)
-    k = keys.shape[0]
-    oh_r, oh_c = _hash_onehots(keys, n_slots, w, k_hashes)
-    rows = jax.lax.dot(oh_r, filt_ref[...].astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)      # [K*k, W]
-    vals = jnp.sum(rows * oh_c, axis=-1).reshape(k, k_hashes)
-    out_ref[...] = jnp.all(vals > 0, axis=-1).astype(jnp.int32)[None, :]
+    out_ref[...] = _member(filt_ref[...], keys_ref[...], n_slots, w,
+                           k_hashes).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("n_slots", "k_hashes", "tile",
@@ -79,7 +95,7 @@ def build_filter(keys, *, n_slots: int, k_hashes: int = 7, tile: int = 256,
 
 
 def _probe_multi_kernel(keys_ref, ti_ref, ns_ref, w_ref, filt_ref, out_ref,
-                        *, wmax, k_hashes):
+                        *, k_hashes):
     """One grid step probes one query tile against one table's filter
     block; contributions land only where the query is assigned to that
     table (accumulator over the table axis -- no data-dependent filter
@@ -90,28 +106,9 @@ def _probe_multi_kernel(keys_ref, ti_ref, ns_ref, w_ref, filt_ref, out_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    keys = keys_ref[...].reshape(-1)
-    ti = ti_ref[...].reshape(-1)
-    ns = ns_ref[...].reshape(-1)
-    w = w_ref[...].reshape(-1)
-    k = keys.shape[0]
-    # Same double hash as _hash_onehots, modulus per query.
-    h1 = (keys * C1) % ns
-    h2 = ((keys * C2) | 1) % ns
-    j = jax.lax.broadcasted_iota(jnp.int32, (k, k_hashes), 1)
-    slots = (h1[:, None] + j * h2[:, None]) % ns[:, None]        # [K, k]
-    row = (slots // w[:, None]).reshape(-1)
-    col = (slots % w[:, None]).reshape(-1)
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (row.shape[0], 128), 1)
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (row.shape[0], wmax), 1)
-    oh_r = (row[:, None] == r_iota).astype(jnp.float32)
-    oh_c = (col[:, None] == c_iota).astype(jnp.float32)
-    rows = jax.lax.dot(oh_r, filt_ref[...].astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)      # [K*k, Wmax]
-    vals = jnp.sum(rows * oh_c, axis=-1).reshape(k, k_hashes)
-    member = jnp.all(vals > 0, axis=-1)
-    out_ref[...] += jnp.where(ti == t, member,
-                              False).astype(jnp.int32)[None, :]
+    member = _member(filt_ref[...], keys_ref[...], ns_ref[...], w_ref[...],
+                     k_hashes)
+    out_ref[...] += (member & (ti_ref[...] == t)).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("k_hashes", "tile", "interpret"))
@@ -126,15 +123,13 @@ def probe_filters_multi(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
     assert k % tile == 0 and fstack.shape[0] % 128 == 0
     t_count = fstack.shape[0] // 128
     wmax = fstack.shape[1]
+    row = pl.BlockSpec((1, tile), lambda i, t: (0, i))
     out = pl.pallas_call(
-        partial(_probe_multi_kernel, wmax=wmax, k_hashes=k_hashes),
+        partial(_probe_multi_kernel, k_hashes=k_hashes),
         grid=(k // tile, t_count),
-        in_specs=[pl.BlockSpec((1, tile), lambda i, t: (0, i)),
-                  pl.BlockSpec((1, tile), lambda i, t: (0, i)),
-                  pl.BlockSpec((1, tile), lambda i, t: (0, i)),
-                  pl.BlockSpec((1, tile), lambda i, t: (0, i)),
+        in_specs=[row, row, row, row,
                   pl.BlockSpec((128, wmax), lambda i, t: (t, 0))],
-        out_specs=pl.BlockSpec((1, tile), lambda i, t: (0, i)),
+        out_specs=row,
         out_shape=jax.ShapeDtypeStruct((1, k), jnp.int32),
         interpret=interpret,
     )(keys.reshape(1, -1), ti.reshape(1, -1), nslots.reshape(1, -1),
@@ -143,7 +138,7 @@ def probe_filters_multi(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
 
 
 def _probe_tiered_kernel(keys_ref, ti_ref, ns_ref, w_ref, filt_ref, out_ref,
-                         *, wmax, k_hashes):
+                         *, k_hashes):
     """Cross-tier twin of ``_probe_multi_kernel``: grid step (i, t) probes
     query tile i against *global* table t's filter block and writes table
     t's own output row -- each (t, i) block is visited exactly once, so no
@@ -151,28 +146,9 @@ def _probe_tiered_kernel(keys_ref, ti_ref, ns_ref, w_ref, filt_ref, out_ref,
     Pallas requirement). The caller segment-sums table rows into tier
     rows."""
     t = pl.program_id(1)
-    keys = keys_ref[...].reshape(-1)
-    ti = ti_ref[...].reshape(-1)                 # GLOBAL assigned table
-    ns = ns_ref[...].reshape(-1)
-    w = w_ref[...].reshape(-1)
-    k = keys.shape[0]
-    # Same double hash as _hash_onehots, modulus per query.
-    h1 = (keys * C1) % ns
-    h2 = ((keys * C2) | 1) % ns
-    j = jax.lax.broadcasted_iota(jnp.int32, (k, k_hashes), 1)
-    slots = (h1[:, None] + j * h2[:, None]) % ns[:, None]        # [K, k]
-    row = (slots // w[:, None]).reshape(-1)
-    col = (slots % w[:, None]).reshape(-1)
-    r_iota = jax.lax.broadcasted_iota(jnp.int32, (row.shape[0], 128), 1)
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (row.shape[0], wmax), 1)
-    oh_r = (row[:, None] == r_iota).astype(jnp.float32)
-    oh_c = (col[:, None] == c_iota).astype(jnp.float32)
-    rows = jax.lax.dot(oh_r, filt_ref[...].astype(jnp.float32),
-                       precision=jax.lax.Precision.HIGHEST)      # [K*k, Wmax]
-    vals = jnp.sum(rows * oh_c, axis=-1).reshape(k, k_hashes)
-    member = jnp.all(vals > 0, axis=-1)
-    out_ref[...] = jnp.where(ti == t, member,
-                             False).astype(jnp.int32)[None, :]
+    member = _member(filt_ref[...], keys_ref[...], ns_ref[...], w_ref[...],
+                     k_hashes)
+    out_ref[...] = (member & (ti_ref[...] == t)).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("k_hashes", "tile", "interpret"))
@@ -187,25 +163,26 @@ def probe_filters_tiered(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
     membership is the segment-sum of its tables' rows. One grid
     (K/tile, Tg), the same total step count as per-tier
     ``probe_filters_multi`` sweeps over every tier, collapsed into ONE
-    launch; VMEM still holds one [128, Wmax] filter block per step."""
+    launch; VMEM still holds one [128, Wmax] filter block per step.
+    Per-table rows run through the kernel as [Tg, 1, K] so a one-row
+    block's last two dims equal the array's."""
     k = keys.shape[0]
     assert k % tile == 0 and fstack.shape[0] % 128 == 0
     t_count = fstack.shape[0] // 128
     assert ti.shape[0] == t_count
     wmax = fstack.shape[1]
-    row_of = lambda i, t: (t, i)                 # noqa: E731
-    return pl.pallas_call(
-        partial(_probe_tiered_kernel, wmax=wmax, k_hashes=k_hashes),
+    row_of = pl.BlockSpec((None, 1, tile), lambda i, t: (t, 0, i))
+    out = pl.pallas_call(
+        partial(_probe_tiered_kernel, k_hashes=k_hashes),
         grid=(k // tile, t_count),
         in_specs=[pl.BlockSpec((1, tile), lambda i, t: (0, i)),
-                  pl.BlockSpec((1, tile), row_of),
-                  pl.BlockSpec((1, tile), row_of),
-                  pl.BlockSpec((1, tile), row_of),
+                  row_of, row_of, row_of,
                   pl.BlockSpec((128, wmax), lambda i, t: (t, 0))],
-        out_specs=pl.BlockSpec((1, tile), row_of),
-        out_shape=jax.ShapeDtypeStruct((t_count, k), jnp.int32),
+        out_specs=row_of,
+        out_shape=jax.ShapeDtypeStruct((t_count, 1, k), jnp.int32),
         interpret=interpret,
-    )(keys.reshape(1, -1), ti, nslots, w, fstack)
+    )(keys.reshape(1, -1), ti[:, None], nslots[:, None], w[:, None], fstack)
+    return out.reshape(t_count, k)
 
 
 @partial(jax.jit, static_argnames=("k_hashes", "tile", "interpret"))

@@ -1,5 +1,6 @@
-"""jit'd wrappers for the Bloom kernels, with padding and a numpy facade
-used by the LSM engine when running with --device-kernels."""
+"""jit'd wrappers for the Bloom kernels, with padding and a numpy facade:
+the ``*_run`` / ``bloom_probe_multi`` entry points are what the Pallas
+execution backend (``StoreConfig(backend="pallas")``) calls."""
 from __future__ import annotations
 
 import jax.numpy as jnp

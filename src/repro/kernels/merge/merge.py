@@ -66,22 +66,22 @@ def _merge_kernel(ka_ref, va_ref, kb_ref, vb_ref, ko_ref, vo_ref, keep_ref):
 
 @partial(jax.jit, static_argnames=("interpret",))
 def merge_tiles(ka, va, kb, vb, *, interpret: bool = True):
-    """ka,kb: [G, Ba]/[G, Bb] sorted int32; returns (keys, vals, keep)."""
+    """ka,kb: [G, Ba]/[G, Bb] sorted int32; returns (keys, vals, keep).
+
+    Operands run through the kernel as [G, 1, B] with a squeezed leading
+    block dim: each block's last two dims then equal the array's, which
+    the TPU lowering requires of a one-row block."""
     g, ba = ka.shape
     bb = kb.shape[1]
     n = ba + bb
-    grid = (g,)
-    bspec = lambda b: pl.BlockSpec((1, b), lambda i: (i, 0))
-    out_shapes = (
-        jax.ShapeDtypeStruct((g, n), jnp.int32),
-        jax.ShapeDtypeStruct((g, n), jnp.int32),
-        jax.ShapeDtypeStruct((g, n), jnp.int32),
-    )
-    return pl.pallas_call(
+    bspec = lambda b: pl.BlockSpec((None, 1, b), lambda i: (i, 0, 0))
+    out_shapes = (jax.ShapeDtypeStruct((g, 1, n), jnp.int32),) * 3
+    outs = pl.pallas_call(
         _merge_kernel,
-        grid=grid,
+        grid=(g,),
         in_specs=[bspec(ba), bspec(ba), bspec(bb), bspec(bb)],
         out_specs=(bspec(n), bspec(n), bspec(n)),
         out_shape=out_shapes,
         interpret=interpret,
-    )(ka, va, kb, vb)
+    )(ka[:, None], va[:, None], kb[:, None], vb[:, None])
+    return tuple(o.reshape(g, n) for o in outs)

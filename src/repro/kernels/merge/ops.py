@@ -20,6 +20,9 @@ from .merge import merge_tiles
 from .ref import merge_tiles_ref
 
 INT_MAX = np.int32(2**31 - 1)
+# Largest tile whose merge kernel fits the TPU's default scoped VMEM
+# (16 MiB): at 512 the [tile, 2*tile] one-hot scatter needs about 18 MiB.
+MERGE_TILE = 256
 
 
 def _diag_splits(ka, kb, diags):
@@ -56,7 +59,7 @@ def _gather_window(x, starts, lens, width, fill):
 
 
 @partial(jax.jit, static_argnames=("tile", "use_kernel", "interpret"))
-def merge_sorted_runs(ka, va, kb, vb, *, tile: int = 512,
+def merge_sorted_runs(ka, va, kb, vb, *, tile: int = MERGE_TILE,
                       use_kernel: bool = True, interpret: bool = True):
     """Merge two sorted non-negative int32 runs with newest-wins dedup.
 
@@ -112,7 +115,7 @@ def _pad_run(k, v, n):
     return k, v
 
 
-def ingest_run(keys, src, *, tile: int = 512, use_kernel: bool = True,
+def ingest_run(keys, src, *, tile: int = MERGE_TILE, use_kernel: bool = True,
                interpret: bool = True):
     """Run-sized write-ingest entry point: dedup a pre-ordered write batch
     through the tile-merge kernel.
@@ -269,7 +272,7 @@ def lookup_store_device(fstack, keys, vals, queries, gti, ns, w, lo, hi, *,
             np.asarray(win[:n]).astype(np.int64))
 
 
-def merge_runs_device(runs, *, tile: int = 512, use_kernel: bool = True,
+def merge_runs_device(runs, *, tile: int = MERGE_TILE, use_kernel: bool = True,
                       interpret: bool = True):
     """Run-sized engine entry point: fold k sorted runs (ordered newest
     first, keys in [0, INT_MAX)) into one deduped run with newest-wins

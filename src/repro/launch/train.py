@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduced as make_reduced
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, init_params, make_shardings
 from repro.runtime.checkpoint import Checkpointer
 from repro.runtime.elastic import Preemption, StragglerMonitor
@@ -26,7 +27,7 @@ from repro.runtime.training import TrainConfig, make_train_step, opt_state_specs
 def parse_mesh(spec: str):
     dims = tuple(int(x) for x in spec.split("x"))
     axes = ("pod", "data", "model")[-len(dims):]
-    return jax.make_mesh(dims, axes)
+    return make_mesh(dims, axes)
 
 
 def main(argv=None):

@@ -4,7 +4,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
 from .params import P
 
 
@@ -57,8 +56,8 @@ def mlp_psum_bf16(p, x, compute_dtype, mesh, data_axes=("pod", "data")):
         y = mlp(p_l, x_l, compute_dtype).astype(jnp.bfloat16)
         return jax.lax.psum(y, "model").astype(compute_dtype)
 
-    return shard_map(fn, mesh=mesh, in_specs=(pspec, xspec),
-                     out_specs=xspec, check_vma=False)(p, x)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(pspec, xspec),
+                         out_specs=xspec, check_vma=False)(p, x)
 
 
 # -- embeddings (tied; gemma-style sqrt(d) input scaling keeps both the

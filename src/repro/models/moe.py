@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from ..compat import shard_map
 from .layers import P
 
 
@@ -121,6 +120,6 @@ def moe_block(cfg, p, x, mesh=None, data_axes=("data",), dense_mlp=None):
             y = y + mlp(rest[0], x_l, x_l.dtype)
         return jax.lax.psum(y, "model")   # ONE fused reduction
 
-    return shard_map(shard_fn, mesh=mesh,
-                     in_specs=specs, out_specs=xspec,
-                     check_vma=False)(*args)
+    return jax.shard_map(shard_fn, mesh=mesh,
+                         in_specs=specs, out_specs=xspec,
+                         check_vma=False)(*args)

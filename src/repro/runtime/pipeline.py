@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from ..compat import shard_map
-
 
 def pipeline_forward(stage_fn, params_stacked, x, mesh, *,
                      stage_axis: str = "stage", microbatches: int = None):
@@ -69,6 +67,6 @@ def pipeline_forward(stage_fn, params_stacked, x, mesh, *,
             jnp.where(sid == 0, outs, jnp.zeros_like(outs)), stage_axis)
         return outs
 
-    return shard_map(shard_fn, mesh=mesh,
-                     in_specs=(p_spec, x_spec), out_specs=x_spec,
-                     check_vma=False)(params_stacked, x)
+    return jax.shard_map(shard_fn, mesh=mesh,
+                         in_specs=(p_spec, x_spec), out_specs=x_spec,
+                         check_vma=False)(params_stacked, x)

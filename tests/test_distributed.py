@@ -33,6 +33,7 @@ from repro.models.params import abstract_params
 from repro.runtime.sharding import activation_sharding, param_rules
 from repro.runtime.training import TrainConfig, make_train_step, opt_state_specs
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.mesh import make_mesh
 
 cfg = reduced(get_config("yi-6b")).with_(num_kv_heads=2)
 model = build_model(cfg)
@@ -46,7 +47,7 @@ batch = jax.tree.map(jnp.asarray, data.batch(0))
 step = make_train_step(model, TrainConfig())
 _, _, m_ref = jax.jit(step)(params, opt, batch)
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = param_rules(fsdp=True, multi_pod=True)
 p_sh = make_shardings(pspec, mesh, rules)
 o_sh = make_shardings(ospec, mesh, rules)

@@ -74,6 +74,15 @@ def test_merge_empty_and_single_run(backends):
         np.testing.assert_array_equal(v, k1 * 2)
 
 
+def test_pallas_interpret_follows_the_device(backends):
+    _, pb = backends
+    if pb.device.platform == "tpu":
+        pytest.skip("this process holds a TPU")
+    assert PallasBackend().interpret        # off a TPU: interpret mode
+    with pytest.raises(ValueError, match="need a TPU"):
+        PallasBackend(interpret=False)
+
+
 def test_merge_out_of_int32_range_falls_back(backends):
     _, pb = backends
     k1 = np.array([1, 2**40], np.int64)          # beyond int32

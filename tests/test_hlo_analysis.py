@@ -59,13 +59,12 @@ def test_collective_bytes_counted_with_group_size():
     code = """
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as PS
-from repro.compat import shard_map
 from repro.utils.hlo import analyze_hlo
 mesh = jax.make_mesh((8,), ("d",))
 def f(x):
-    return shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
-                     in_specs=PS("d"), out_specs=PS(),
-                     check_vma=False)(x)
+    return jax.shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
+                         in_specs=PS("d"), out_specs=PS(),
+                         check_vma=False)(x)
 x = jax.ShapeDtypeStruct((1024, 128), jnp.float32,
                          sharding=NamedSharding(mesh, PS("d")))
 hlo = jax.jit(f).lower(x).compile().as_text()
