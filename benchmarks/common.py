@@ -158,16 +158,17 @@ def measure(store, fn) -> dict:
     Accepts a bare ``LSMStore`` or a ``StorageService``. ``write_stalls``
     (backpressure deferrals) is surfaced as the ``stalls`` row field.
 
-    Backend jit-shape-cache deltas (compiles vs cache hits over the
-    measured window -- recompile churn from new pow2 buckets, e.g. the
-    fused read path's tier stacks) land on the ``IOStats`` delta and the
-    row; when the store runs a device page pool, the window's fused-tier
-    hit rate rides along as ``device_pool_hit_rate``.
+    Backend compile deltas (JAX compile events inside jitted backend
+    calls vs calls that raised none, over the measured window --
+    recompile churn from new pow2 buckets, e.g. the fused read path's
+    tier stacks) land on the row; when the store runs a device page
+    pool, the window's fused-tier hit rate rides along as
+    ``device_pool_hit_rate``.
 
     When measuring a ``StorageService``, the window's request-latency and
     maintenance-stall tails (from the service's streaming histograms)
-    land on the delta and the row as ``p50_us`` / ``p99_us`` /
-    ``p999_us`` / ``max_stall_us`` -- the tail-latency SLO columns."""
+    land on the row as ``p50_us`` / ``p99_us`` / ``p999_us`` /
+    ``max_stall_us`` -- the tail-latency SLO columns."""
     service = store if isinstance(store, StorageService) else None
     store = getattr(store, "store", store)     # unwrap a StorageService
     backend = getattr(store, "backend", None) \
@@ -183,15 +184,6 @@ def measure(store, fn) -> dict:
     store.sync_mem_stats()
     d = store.disk.stats.delta(before)
     js1 = backend.jit_stats()
-    d.jit_compiles = js1["jit_compiles"] - js0["jit_compiles"]
-    d.jit_cache_hits = js1["jit_cache_hits"] - js0["jit_cache_hits"]
-    if service is not None:
-        dl = service.latency.delta(lat0)
-        ds = service.stall.delta(stall0)
-        d.lat_p50_us = dl.p50
-        d.lat_p99_us = dl.p99
-        d.lat_p999_us = dl.p999
-        d.max_stall_us = ds.max_value
     io, cpu = store.cfg.time_model.elapsed(d, scheme=store.cfg.scheme)
     ops = max(d.ops, 1)
     out = {
@@ -205,8 +197,8 @@ def measure(store, fn) -> dict:
         "stalls": d.write_stalls,
         "flushes_log": d.flushes_log,
         "flushes_mem": d.flushes_mem,
-        "jit_compiles": d.jit_compiles,
-        "jit_cache_hits": d.jit_cache_hits,
+        "jit_compiles": js1["jit_compiles"] - js0["jit_compiles"],
+        "jit_cache_hits": js1["jit_cache_hits"] - js0["jit_cache_hits"],
         # One-launch read path: device launches over the window and the
         # average number of lookup tiers each launch covered (per-tier
         # fused -> ~1.0; cross-tier fused -> the whole store per launch).
@@ -222,10 +214,11 @@ def measure(store, fn) -> dict:
         "flush_slices": d.flush_slices,
     }
     if service is not None:
-        out["p50_us"] = d.lat_p50_us
-        out["p99_us"] = d.lat_p99_us
-        out["p999_us"] = d.lat_p999_us
-        out["max_stall_us"] = d.max_stall_us
+        dl = service.latency.delta(lat0)
+        out["p50_us"] = dl.p50
+        out["p99_us"] = dl.p99
+        out["p999_us"] = dl.p999
+        out["max_stall_us"] = service.stall.delta(stall0).max_value
     if ps0 is not None:
         ps1 = pool.stats()
         dh = (ps1["tier_hits"] - ps0["tier_hits"]

@@ -36,6 +36,7 @@ from dataclasses import dataclass
 import numpy as np
 
 from ...kernels.sizing import next_pow2, slots_for  # jax-free module
+from ...runtime import tracing
 
 ENV_VAR = "REPRO_LSM_BACKEND"
 
@@ -166,32 +167,50 @@ def assign_bounds(starts, ends, qkeys):
     return ti.astype(np.int64), ok
 
 
+class _JitCall:
+    """Marks one jitted backend call: the JAX compile events the calling
+    thread raises inside it count as compiles, and a call that raised
+    none as a cache hit."""
+
+    __slots__ = ("backend", "c0")
+
+    def __init__(self, backend):
+        self.backend = backend
+
+    def __enter__(self):
+        self.c0 = tracing.compile_events()
+
+    def __exit__(self, *exc):
+        n = tracing.compile_events() - self.c0
+        if n:
+            self.backend.jit_compiles += n
+        else:
+            self.backend.jit_cache_hits += 1
+        return False
+
+
 class ExecutionBackend:
     """Interface of the engine's batched primitives.
 
-    Backends also keep jit-shape-bucket cache counters
-    (``jit_compiles`` / ``jit_cache_hits``): every jitted entry point notes
-    the pow2 shape bucket it is about to run under, counting a compile the
-    first time a bucket is seen and a cache hit afterwards. The reference
-    backend jits nothing, so its counters stay zero; benchmarks surface
-    the deltas so recompile churn from new shape buckets (e.g. the fused
+    Backends also keep compile counters (``jit_compiles`` /
+    ``jit_cache_hits``): every jitted entry point runs its device work
+    inside ``with self._note_jit():``, which counts the JAX compile
+    events (programs built or loaded from a persistent cache) raised
+    inside it, or one cache hit for a call that raised none. The
+    reference backend jits nothing, so its counters stay zero;
+    benchmarks surface the deltas so recompile churn (e.g. the fused
     read path's tier stacks) is observable in ``BENCH_*.json`` rows.
     """
 
     name: str = "abstract"
 
     def __init__(self):
-        self._jit_shapes: set = set()
         self.jit_compiles = 0
         self.jit_cache_hits = 0
 
-    def _note_jit(self, *key) -> None:
-        """Record one jitted call under shape-bucket ``key``."""
-        if key in self._jit_shapes:
-            self.jit_cache_hits += 1
-        else:
-            self._jit_shapes.add(key)
-            self.jit_compiles += 1
+    def _note_jit(self) -> _JitCall:
+        """Mark one jitted call (a context manager around it)."""
+        return _JitCall(self)
 
     def jit_stats(self) -> dict:
         return {"jit_compiles": self.jit_compiles,

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from ...runtime import tracing
 from .backend import (BLOOM_K_HASHES, ExecutionBackend, FusedLookup,
                       StoreLookup, StoreView, TierView, assign_bounds,
                       bloom_sizing, next_pow2, register_backend)
@@ -48,14 +49,15 @@ class PallasBackend(ExecutionBackend):
     def __init__(self, *, interpret: bool | None = None,
                  k_hashes: int = BLOOM_K_HASHES, fused_wmax: int = 1024):
         super().__init__()
-        import jax
         import jax.numpy as jnp
 
+        from repro.kernels import transfer
         from repro.kernels.bloom import ops as bloom_ops
         from repro.kernels.merge import ops as merge_ops
         self._bloom_ops = bloom_ops
         self._merge_ops = merge_ops
-        self._jnp = jnp
+        self._transfer = transfer
+        tracing.listen_compiles()
         # The device every operand is placed on decides how the kernels
         # run: compiled on a TPU, interpreted anywhere else. Asking for
         # compiled kernels off a TPU is an error, never a quiet fallback.
@@ -73,7 +75,6 @@ class PallasBackend(ExecutionBackend):
         # resident: bounds the kernel's one-hot working set to VMEM scale.
         self.fused_wmax = fused_wmax
         self._fallback = NumpyBackend(k_hashes=k_hashes)
-        self._searchsorted = jax.jit(lambda a, v: jnp.searchsorted(a, v))
         # Calls sent off the device path: out-of-int32-domain operands,
         # numpy-built filters, and tiers too wide for the fused probe.
         self.fallback_calls = 0
@@ -87,10 +88,9 @@ class PallasBackend(ExecutionBackend):
                 and _int32_safe_vals([v for _, v in runs])):
             self.fallback_calls += 1
             return self._fallback.merge_runs(runs)
-        self._note_jit("merge",
-                       tuple(next_pow2(len(k)) for k, _ in runs))
-        keys, vals = self._merge_ops.merge_runs_device(
-            runs, interpret=self.interpret)
+        with self._note_jit():
+            keys, vals = self._merge_ops.merge_runs_device(
+                runs, interpret=self.interpret)
         return keys.astype(np.int64), vals.astype(np.int64)
 
     # -- write ingest --------------------------------------------------------
@@ -113,11 +113,10 @@ class PallasBackend(ExecutionBackend):
             self.fallback_calls += 1
             return self._fallback.ingest_run(keys, vals)
         order = ingest_order(keys)
-        h = n // 2
-        self._note_jit("ingest", next_pow2(h), next_pow2(n - h))
-        ks, src = self._merge_ops.ingest_run(
-            keys[order].astype(np.int32), order.astype(np.int32),
-            interpret=self.interpret)
+        with self._note_jit():
+            ks, src = self._merge_ops.ingest_run(
+                keys[order].astype(np.int32), order.astype(np.int32),
+                interpret=self.interpret)
         src = src.astype(np.int64)
         return ks.astype(np.int64), vals[src], src
 
@@ -128,14 +127,14 @@ class PallasBackend(ExecutionBackend):
         if not _int32_safe_sorted(keys):
             self.fallback_calls += 1
             return ("numpy", self._fallback.bloom_build(keys))
-        self._note_jit("bloom_build", n_pad, n_slots)
-        filt = self._bloom_ops.bloom_build_run(
-            keys, n_keys_padded=n_pad, n_slots=n_slots,
-            k_hashes=self.k_hashes, interpret=self.interpret)
+        with self._note_jit():
+            filt = self._bloom_ops.bloom_build_run(
+                keys, n_keys_padded=n_pad, n_slots=n_slots,
+                k_hashes=self.k_hashes, interpret=self.interpret)
         # Cache membership bits on the host, not the kernel's int32 counts:
         # filters live as long as their SSTable, so resident size matters
         # (bool is 4x smaller; re-widened to int32 at probe time).
-        return ("pallas", np.asarray(filt) != 0)
+        return ("pallas", self._transfer.to_host(filt) != 0)
 
     def bloom_probe(self, filt, keys):
         keys = np.asarray(keys)
@@ -154,10 +153,10 @@ class PallasBackend(ExecutionBackend):
             # impossible for keys that were inserted via the same wrap.
             self.fallback_calls += 1
             return self._fallback.bloom_probe(f.reshape(-1), keys)
-        self._note_jit("bloom_probe", f.shape,
-                       next_pow2(len(keys), lo=256))
-        return self._bloom_ops.bloom_probe_run(
-            f, keys, k_hashes=self.k_hashes, interpret=self.interpret)
+        with self._note_jit():
+            out = self._bloom_ops.bloom_probe_run(
+                f, keys, k_hashes=self.k_hashes, interpret=self.interpret)
+        return out
 
     # -- point lookups -------------------------------------------------------
     def lookup_batch(self, sorted_keys, queries):
@@ -174,14 +173,14 @@ class PallasBackend(ExecutionBackend):
         # (never matched -- keys are int32-safe), queries pad by repeating
         # their last element (results discarded).
         n, q = len(sorted_keys), len(queries)
-        self._note_jit("lookup", next_pow2(n), next_pow2(q))
         sk = np.pad(sorted_keys.astype(np.int32),
                     (0, next_pow2(n) - n), constant_values=_INT32_MAX)
         qk = np.pad(queries.astype(np.int32),
                     (0, next_pow2(q) - q), mode="edge")
-        jnp = self._jnp
-        pos = np.asarray(self._searchsorted(jnp.asarray(sk),
-                                            jnp.asarray(qk)))[:q]
+        tr = self._transfer
+        with self._note_jit():
+            pos = tr.to_host(self._merge_ops.search_sorted_run(
+                tr.to_device(sk), tr.to_device(qk)))[:q]
         pos = np.minimum(pos.astype(np.int64), n)
         inb = pos < n
         found = np.zeros(q, bool)
@@ -225,11 +224,11 @@ class PallasBackend(ExecutionBackend):
         cv = np.zeros(npad, np.int32)
         ck[:total] = np.concatenate(keys_list)
         cv[:total] = np.concatenate([t.vals for t in tables])
-        jnp = self._jnp
+        up = self._transfer.to_device
         payload = {
-            "keys": jnp.asarray(ck),
-            "vals": jnp.asarray(cv),
-            "fstack": jnp.asarray(fstack),
+            "keys": up(ck),
+            "vals": up(cv),
+            "fstack": up(fstack),
             "nslots_t": np.array([128 * f.shape[1] for f in filts],
                                  np.int32),
             "w_t": np.array([f.shape[1] for f in filts], np.int32),
@@ -252,18 +251,16 @@ class PallasBackend(ExecutionBackend):
             return None
         p = view.payload
         ti, ok = assign_bounds(view.starts, view.ends, q.astype(np.int64))
-        kpad = next_pow2(max(1, len(q)), lo=256)
-        self._note_jit("fused_bloom", view.num_tables,
-                       int(p["fstack"].shape[1]), kpad)
-        positive = self._bloom_ops.bloom_probe_multi(
-            p["fstack"], q.astype(np.int32), ti.astype(np.int32),
-            p["nslots_t"][ti], p["w_t"][ti],
-            k_hashes=self.k_hashes, interpret=self.interpret)
+        with self._note_jit():
+            positive = self._bloom_ops.bloom_probe_multi(
+                p["fstack"], q.astype(np.int32), ti.astype(np.int32),
+                p["nslots_t"][ti], p["w_t"][ti],
+                k_hashes=self.k_hashes, interpret=self.interpret)
         lo = view.offs[ti].astype(np.int32)
         hi = (view.offs[ti] + view.lens[ti]).astype(np.int32)
-        self._note_jit("fused_lookup", p["npad"], kpad)
-        abs_pos, hit, vals = self._merge_ops.lookup_runs_device(
-            p["keys"], p["vals"], lo, hi, q.astype(np.int32))
+        with self._note_jit():
+            abs_pos, hit, vals = self._merge_ops.lookup_runs_device(
+                p["keys"], p["vals"], lo, hi, q.astype(np.int32))
         return FusedLookup(ti=ti, ok=ok, positive=positive,
                            pos=(abs_pos - view.offs[ti]).astype(np.int64),
                            hit=hit, vals=vals.astype(np.int64))
@@ -308,11 +305,11 @@ class PallasBackend(ExecutionBackend):
         if total:
             ck[:total] = np.concatenate([t.keys for t in tables])
             cv[:total] = np.concatenate([t.vals for t in tables])
-        jnp = self._jnp
+        up = self._transfer.to_device
         payload = {
-            "keys": jnp.asarray(ck),
-            "vals": jnp.asarray(cv),
-            "fstack": jnp.asarray(fstack),
+            "keys": up(ck),
+            "vals": up(cv),
+            "fstack": up(fstack),
             "nslots_t": np.array([128 * f.shape[1] for f in filts],
                                  np.int32),
             "w_t": np.array([f.shape[1] for f in filts], np.int32),
@@ -336,9 +333,11 @@ class PallasBackend(ExecutionBackend):
 
     def lookup_store_fused(self, view, queries):
         """ONE device launch for the whole store: the composed
-        ``lookup_store_device`` jit fuses the stacked Bloom probe, the
+        ``_store_probe`` jit fuses the stacked Bloom probe, the
         cross-tier ranged sorted probe, and the newest-wins tier argmin,
-        in place of the per-tier fused path's two launches *per tier*."""
+        in place of the per-tier fused path's two launches *per tier*.
+        The host work before the launch is the ``read.probe_prep``
+        span."""
         q = np.asarray(queries)
         if not _int32_safe_keys([q]):
             self.fallback_calls += 1
@@ -352,25 +351,27 @@ class PallasBackend(ExecutionBackend):
                 pos=np.zeros((0, K), np.int64), hit=np.zeros((0, K), bool),
                 vals=np.zeros((0, K), np.int64),
                 win=np.full(K, -1, np.int64))
-        q64 = q.astype(np.int64)
-        ti = np.empty((R, K), np.int64)
-        ok = np.empty((R, K), bool)
-        lo = np.empty((R, K), np.int64)
-        hi = np.empty((R, K), np.int64)
-        for r in range(R):
-            ti[r], ok[r] = assign_bounds(view.tier_starts[r],
-                                         view.tier_ends[r], q64)
-            lo[r] = view.tier_offs[r][ti[r]]
-            hi[r] = lo[r] + view.tier_lens[r][ti[r]]
-        gti = p["t_off"][:, None] + ti
-        kpad = next_pow2(max(1, K), lo=256)
-        self._note_jit("store_fused", p["tier_of"],
-                       int(p["fstack"].shape[1]), p["npad"], kpad)
-        member, abs_pos, hit, vals, win = self._merge_ops.lookup_store_device(
-            p["fstack"], p["keys"], p["vals"], q.astype(np.int32),
-            gti, p["nslots_t"][gti], p["w_t"][gti], lo, hi,
-            tier_of=p["tier_of"], k_hashes=self.k_hashes,
-            interpret=self.interpret)
+        ops = self._merge_ops
+        with tracing.span("read.probe_prep"):
+            q64 = q.astype(np.int64)
+            ti = np.empty((R, K), np.int64)
+            ok = np.empty((R, K), bool)
+            lo = np.empty((R, K), np.int64)
+            hi = np.empty((R, K), np.int64)
+            for r in range(R):
+                ti[r], ok[r] = assign_bounds(view.tier_starts[r],
+                                             view.tier_ends[r], q64)
+                lo[r] = view.tier_offs[r][ti[r]]
+                hi[r] = lo[r] + view.tier_lens[r][ti[r]]
+            gti = p["t_off"][:, None] + ti
+            operands, n = ops.store_probe_operands(
+                q.astype(np.int32), gti, p["nslots_t"][gti],
+                p["w_t"][gti], lo, hi, p["tier_of"])
+        with self._note_jit():
+            member, abs_pos, hit, vals, win = ops.run_store_probe(
+                p["fstack"], p["keys"], p["vals"], operands, n,
+                tier_of=p["tier_of"], k_hashes=self.k_hashes,
+                interpret=self.interpret)
         return StoreLookup(ti=ti, ok=ok, positive=member,
                            pos=(abs_pos - lo).astype(np.int64),
                            hit=hit, vals=vals, win=win)

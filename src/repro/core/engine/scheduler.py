@@ -62,6 +62,8 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from ...runtime import tracing
+
 _INF = 2**62
 _UNSET = object()      # tick(): "no override" vs an explicit None (=drain)
 
@@ -177,22 +179,29 @@ class SegmentedScheduler:
         self.segments += 1
         rep = TickReport()
         if name == "upkeep":
-            rep.upkeep_steps = self._mem_upkeep()
-            rep.flushes = self._flush_pending()
+            with tracing.span("tick.upkeep"):
+                rep.upkeep_steps = self._mem_upkeep()
+            with tracing.span("tick.flush"):
+                rep.flushes = self._flush_pending()
         elif name == "mem":
-            rep.flushes = self._enforce_memory()
+            with tracing.span("tick.flush"):
+                rep.flushes = self._enforce_memory()
         elif name == "log":
-            rep.flushes = self._enforce_log()
+            with tracing.span("tick.flush"):
+                rep.flushes = self._enforce_log()
         elif name == "merge":
             budget = self.merge_budget if merge_budget is _UNSET \
                 else merge_budget
-            rep.merge_steps = self._run_merges(budget)
-        else:                                     # "wal"
-            enforce_wal(arena, self)
+            with tracing.span("tick.merge"):
+                rep.merge_steps = self._run_merges(budget)
         rep.carried_debt = self.carried_debt
-        # Commit point: the segment's TickRecord (and any still-pending
-        # writes) reach stable storage under the configured fsync policy.
-        arena.wal.commit()
+        with tracing.span("tick.wal"):
+            if name == "wal":
+                enforce_wal(arena, self)
+            # Commit point: the segment's TickRecord (and any still-pending
+            # writes) reach stable storage under the configured fsync
+            # policy.
+            arena.wal.commit()
         return rep
 
     # -- background prepare (engine/workers.py) -------------------------------
@@ -236,15 +245,19 @@ class SegmentedScheduler:
         arena.wal.append_tick(_budget_tag(merge_budget), segment="full")
         self.ticks += 1
         rep = TickReport()
-        rep.upkeep_steps = self._mem_upkeep()
-        rep.flushes += self._flush_pending()
-        rep.flushes += self._enforce_memory()
-        rep.flushes += self._enforce_log()
+        with tracing.span("tick.upkeep"):
+            rep.upkeep_steps = self._mem_upkeep()
+        with tracing.span("tick.flush"):
+            rep.flushes += self._flush_pending()
+            rep.flushes += self._enforce_memory()
+            rep.flushes += self._enforce_log()
         budget = self.merge_budget if merge_budget is _UNSET else merge_budget
-        rep.merge_steps = self._run_merges(budget)
+        with tracing.span("tick.merge"):
+            rep.merge_steps = self._run_merges(budget)
         rep.carried_debt = self.carried_debt
-        enforce_wal(arena, self)
-        arena.wal.commit()        # commit point (see run_segment)
+        with tracing.span("tick.wal"):
+            enforce_wal(arena, self)
+            arena.wal.commit()    # commit point (see run_segment)
         return rep
 
 

@@ -45,17 +45,6 @@ class IOStats:
                                     # report as fused_tiers_per_launch
     fused_tier_hits: int = 0        # covered tiers that resolved >= 1
     fused_tier_misses: int = 0      # query vs. those that resolved none
-    jit_compiles: int = 0           # backend jit shape-bucket compiles
-    jit_cache_hits: int = 0         # backend jit shape-bucket cache hits
-                                    # (both 0 on store paths; benchmark
-                                    # windows populate them from
-                                    # ExecutionBackend.jit_stats deltas)
-    lat_p50_us: float = 0.0         # request-latency tail of a measurement
-    lat_p99_us: float = 0.0         # window -- 0.0 on store paths;
-    lat_p999_us: float = 0.0        # benchmark windows populate them from
-    max_stall_us: float = 0.0       # the service's LatencyHistogram deltas
-                                    # (max_stall = longest maintenance
-                                    # pause inside one submit/drain call)
     bg_segments: int = 0            # maintenance prepare units (merge
                                     # sort/dedup, Bloom builds) consumed
                                     # from a background worker instead of

@@ -17,6 +17,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ...runtime import tracing
 from ..engine import get_backend
 from .sstable import (SSTable, partition_run, probe_tier,
                       sstable_from_run)
@@ -348,19 +349,22 @@ class PartitionedMemComponent(MemComponentBase):
         n = len(keys)
         found = np.zeros(n, bool)
         vals = np.zeros(n, np.int64)
-        if self.active:
-            a = self.active
-            for i, k in enumerate(keys.tolist()):
-                hit = a.get(k)
-                if hit is not None:
-                    found[i] = True
-                    vals[i] = hit[0]
-        unresolved = ~found
-        for lvl in self.levels:                  # newest level first
-            if not unresolved.any():
-                break
-            probe_tier(lvl, keys, found, vals, unresolved,
-                       self.backend.lookup_batch)
+        with tracing.span("mem.search"):
+            if self.active:
+                a = self.active
+                for i, k in enumerate(keys.tolist()):
+                    hit = a.get(k)
+                    if hit is not None:
+                        found[i] = True
+                        vals[i] = hit[0]
+            unresolved = ~found
+            searched = 0
+            for lvl in self.levels:              # newest level first
+                if not unresolved.any():
+                    break
+                searched += probe_tier(lvl, keys, found, vals, unresolved,
+                                       self.backend.lookup_batch)
+            tracing.count("mem.tables_searched", searched)
         return found, vals
 
     def scan_runs(self, lo: int, hi: int):

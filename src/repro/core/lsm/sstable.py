@@ -87,12 +87,15 @@ def probe_tier(tables, keys, found, vals, unresolved, lookup_batch, *,
       pre_probe(sst, qk) -> bool mask of probes worth a binary search
         (the tree pins Bloom pages and probes the filter here);
       post_lookup(sst, pos, hit) (the tree pins leaf pages here).
+
+    Returns the number of tables searched (``lookup_batch`` calls).
     """
     idx_un = np.flatnonzero(unresolved)
     if not len(idx_un) or not tables:
-        return
+        return 0
     q = keys[idx_un]
     ti, ok = assign_queries(tables, q)
+    searched = 0
     for t_i in np.unique(ti[ok]):
         sst = tables[t_i]
         sel = np.flatnonzero(ok & (ti == t_i))
@@ -102,12 +105,14 @@ def probe_tier(tables, keys, found, vals, unresolved, lookup_batch, *,
                 continue
             sel = sel[positive]
         pos, hit = lookup_batch(sst.keys, q[sel])
+        searched += 1
         if post_lookup is not None:
             post_lookup(sst, pos, hit)
         gidx = idx_un[sel[hit]]
         found[gidx] = True
         vals[gidx] = sst.vals[pos[hit]]
         unresolved[gidx] = False
+    return searched
 
 
 @dataclass(eq=False)  # identity equality: SSTables live in Python lists

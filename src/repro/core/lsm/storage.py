@@ -20,6 +20,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ...runtime import tracing
 from ..engine import available_backends, get_backend
 from ..engine.scheduler import MaintenanceScheduler
 from .arena import MemoryArena
@@ -409,22 +410,24 @@ class LSMStore:
     def _ingest(self, tree_name: str, keys, vals, *, op: bool,
                 tick: bool, delete: bool = False) -> None:
         tree = self.trees[tree_name]
-        # Write-ahead: the batch is logged (assigning lsn0 = the current
-        # log position and advancing the head by the payload bytes) before
-        # it touches the memory component. During crash-recovery replay
-        # the same call hands back the record's original LSN instead.
-        lsn0 = self.arena.wal.append_batch(
-            tree_name, keys, None if delete else vals,
-            entry_bytes=tree.entry_bytes, op=op, delete=delete)
-        tree.write_batch(keys, vals, lsn0)
-        nbytes = len(keys) * tree.entry_bytes
-        self.disk.stats.entries_written += len(keys)
-        if op:
-            self.disk.stats.ops += len(keys)
-        win = self._rate_win[tree_name]
-        win.append((lsn0, nbytes))
-        self._trim_rate_windows()
-        self._dataset_touch(tree_name)
+        with tracing.span("write.ingest"):
+            # Write-ahead: the batch is logged (assigning lsn0 = the
+            # current log position and advancing the head by the payload
+            # bytes) before it touches the memory component. During
+            # crash-recovery replay the same call hands back the record's
+            # original LSN instead.
+            lsn0 = self.arena.wal.append_batch(
+                tree_name, keys, None if delete else vals,
+                entry_bytes=tree.entry_bytes, op=op, delete=delete)
+            tree.write_batch(keys, vals, lsn0)
+            nbytes = len(keys) * tree.entry_bytes
+            self.disk.stats.entries_written += len(keys)
+            if op:
+                self.disk.stats.ops += len(keys)
+            win = self._rate_win[tree_name]
+            win.append((lsn0, nbytes))
+            self._trim_rate_windows()
+            self._dataset_touch(tree_name)
         if tick:
             self.scheduler.tick()
 

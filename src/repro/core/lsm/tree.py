@@ -14,6 +14,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ...runtime import tracing
 from ..engine import get_backend
 from .cache import Disk
 from .grouped_l0 import FlatL0, GroupedL0
@@ -565,22 +566,25 @@ class LSMTree:
         st.fused_launches += 1
         st.fused_tiers += len(tiers)
         win = r.win
-        for rr, tier in enumerate(tiers):
-            # Staged-order activity: a query reaches tier rr iff no newer
-            # tier resolved it.
-            active = (win == -1) | (win >= rr)
-            sel0 = np.flatnonzero(r.ok[rr] & active)
-            if len(sel0):
-                order = sel0[np.argsort(r.ti[rr][sel0], kind="stable")]
-                tis = r.ti[rr][order]
-                starts = np.flatnonzero(np.r_[True, tis[1:] != tis[:-1]])
-                self._replay_tier_pins(self._pin_meta(view, rr, tier),
-                                       tis, starts, r.positive[rr][order],
-                                       r.pos[rr][order], r.hit[rr][order])
-            if (win == rr).any():
-                st.fused_tier_hits += 1
-            else:
-                st.fused_tier_misses += 1
+        with tracing.span("read.pin_replay"):
+            for rr, tier in enumerate(tiers):
+                # Staged-order activity: a query reaches tier rr iff no
+                # newer tier resolved it.
+                active = (win == -1) | (win >= rr)
+                sel0 = np.flatnonzero(r.ok[rr] & active)
+                if len(sel0):
+                    order = sel0[np.argsort(r.ti[rr][sel0], kind="stable")]
+                    tis = r.ti[rr][order]
+                    starts = np.flatnonzero(
+                        np.r_[True, tis[1:] != tis[:-1]])
+                    self._replay_tier_pins(
+                        self._pin_meta(view, rr, tier), tis, starts,
+                        r.positive[rr][order], r.pos[rr][order],
+                        r.hit[rr][order])
+                if (win == rr).any():
+                    st.fused_tier_hits += 1
+                else:
+                    st.fused_tier_misses += 1
         res = np.flatnonzero(win >= 0)
         gidx = idx_un[res]
         found[gidx] = True

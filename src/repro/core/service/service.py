@@ -34,6 +34,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ...runtime import tracing
 from ...runtime.latency import LatencyHistogram
 from ..engine.pacer import MaintenancePacer
 from ..lsm.storage import LSMStore, POLICIES, StoreConfig
@@ -312,13 +313,23 @@ class StorageService:
         results in submission order (``Deferred`` for refused writes --
         over a sharded store, refusal is per shard, and a Deferred may
         carry a request narrowed to the keys that did not execute)."""
+        with tracing.span("service.submit", submit=self.submits + 1) as rec:
+            return self._submit(requests, session, count_ops, rec)
+
+    def _submit(self, requests, session, count_ops, rec) -> list[Result]:
         t0 = time.perf_counter()
         requests = list(requests)
-        plan = build_plan(requests,
-                          router=getattr(self.store, "router", None))
+        with tracing.span("service.plan"):
+            plan = build_plan(requests,
+                              router=getattr(self.store, "router", None))
         if plan.n_requests == 0:
             return []
         self.submits += 1
+        if rec is not None:              # recording: the submit's keys
+            rec.attrs["gets"] = sum(st.n_keys for st in plan.steps
+                                    if st.kind == "get")
+            rec.attrs["puts"] = sum(st.n_keys for st in plan.steps
+                                    if st.kind == "put")
         if session is not None:
             session._begin_submit()
         results: list = [None] * plan.n_requests
@@ -373,13 +384,14 @@ class StorageService:
             else:
                 sel = np.sort(np.concatenate(sels))
                 results[i] = Deferred(self._narrow(r, sel), reason)
-        mem_plan = self.governor.observe(self)
-        if mem_plan is not None:
-            self._apply_plan(mem_plan)
-        if self.stall_governor is not None:
-            pace_plan = self.stall_governor.observe(self)
-            if pace_plan is not None:
-                self._apply_plan(pace_plan)
+        with tracing.span("service.governor"):
+            mem_plan = self.governor.observe(self)
+            if mem_plan is not None:
+                self._apply_plan(mem_plan)
+            if self.stall_governor is not None:
+                pace_plan = self.stall_governor.observe(self)
+                if pace_plan is not None:
+                    self._apply_plan(pace_plan)
         self.latency.record((time.perf_counter() - t0) * 1e6,
                             n=plan.n_requests)
         return results

@@ -4,9 +4,9 @@ execution backend (``StoreConfig(backend="pallas")``) calls."""
 from __future__ import annotations
 
 import jax.numpy as jnp
-import numpy as np
 
 from ..sizing import next_pow2, slots_for  # noqa: F401  (re-exported)
+from ..transfer import to_device, to_host
 from .bloom import (build_filter, probe_filter, probe_filters_multi,
                     probe_filters_tiered)
 from .ref import build_ref, probe_multi_ref, probe_ref, probe_tiered_ref
@@ -14,7 +14,7 @@ from .ref import build_ref, probe_multi_ref, probe_ref, probe_tiered_ref
 
 def bloom_build(keys, *, bits_per_key: int = 10, k_hashes: int = 7,
                 use_kernel: bool = True, interpret: bool = True):
-    keys = jnp.asarray(keys, jnp.int32)
+    keys = to_device(keys, jnp.int32)
     n_slots = slots_for(keys.shape[0], bits_per_key)
     tile = 256
     pad = (-keys.shape[0]) % tile
@@ -38,7 +38,7 @@ def bloom_build_run(keys, *, n_keys_padded: int | None = None,
     filter at exactly ``n_slots``, so an engine that buckets run sizes
     reuses compiled kernels across SSTables of similar size.
     """
-    keys = jnp.asarray(keys, jnp.int32)
+    keys = to_device(keys, jnp.int32)
     n = keys.shape[0]
     assert n >= 1, "empty key set"
     if n_keys_padded is None:
@@ -65,8 +65,8 @@ def bloom_probe_run(filt, keys, *, k_hashes: int = 7,
     as bool to cut resident size); it is widened to the kernel's int32
     on-device, so only the 1-byte representation crosses the host boundary.
     """
-    filt = jnp.asarray(filt).astype(jnp.int32)
-    keys = jnp.asarray(keys, jnp.int32)
+    filt = to_device(filt).astype(jnp.int32)
+    keys = to_device(keys, jnp.int32)
     n = keys.shape[0]
     m = next_pow2(max(1, n), lo=256)
     if m > n:
@@ -76,7 +76,7 @@ def bloom_probe_run(filt, keys, *, k_hashes: int = 7,
                            interpret=interpret)
     else:
         out = probe_ref(filt, keys, k_hashes)
-    return np.asarray(out[:n]).astype(bool)
+    return to_host(out[:n]).astype(bool)
 
 
 def bloom_probe_multi(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
@@ -87,11 +87,11 @@ def bloom_probe_multi(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
     padded with ti=-1 (never a member), so fused probes across tiers of
     the same (T, Wmax, K-bucket) share compiled kernels.
     """
-    fstack = jnp.asarray(fstack).astype(jnp.int32)
-    keys = jnp.asarray(keys, jnp.int32)
-    ti = jnp.asarray(ti, jnp.int32)
-    nslots = jnp.asarray(nslots, jnp.int32)
-    w = jnp.asarray(w, jnp.int32)
+    fstack = to_device(fstack).astype(jnp.int32)
+    keys = to_device(keys, jnp.int32)
+    ti = to_device(ti, jnp.int32)
+    nslots = to_device(nslots, jnp.int32)
+    w = to_device(w, jnp.int32)
     n = keys.shape[0]
     m = next_pow2(max(1, n), lo=256)
     if m > n:
@@ -105,7 +105,7 @@ def bloom_probe_multi(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
                                   k_hashes=k_hashes, interpret=interpret)
     else:
         out = probe_multi_ref(fstack, keys, ti, nslots, w, k_hashes)
-    return np.asarray(out[:n]).astype(bool)
+    return to_host(out[:n]).astype(bool)
 
 
 def bloom_probe_tiered(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
@@ -121,11 +121,11 @@ def bloom_probe_tiered(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
     per-table matrix; a tier's membership is the OR over its tables'
     rows.
     """
-    fstack = jnp.asarray(fstack).astype(jnp.int32)
-    keys = jnp.asarray(keys, jnp.int32)
-    ti = jnp.asarray(ti, jnp.int32)
-    nslots = jnp.asarray(nslots, jnp.int32)
-    w = jnp.asarray(w, jnp.int32)
+    fstack = to_device(fstack).astype(jnp.int32)
+    keys = to_device(keys, jnp.int32)
+    ti = to_device(ti, jnp.int32)
+    nslots = to_device(nslots, jnp.int32)
+    w = to_device(w, jnp.int32)
     t_count = ti.shape[0]
     n = keys.shape[0]
     m = next_pow2(max(1, n), lo=256)
@@ -144,12 +144,12 @@ def bloom_probe_tiered(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
                                    interpret=interpret)
     else:
         out = probe_tiered_ref(fstack, keys, ti, nslots, w, k_hashes)
-    return np.asarray(out[:, :n]).astype(bool)
+    return to_host(out[:, :n]).astype(bool)
 
 
 def bloom_probe(filt, keys, *, k_hashes: int = 7, use_kernel: bool = True,
                 interpret: bool = True):
-    keys = jnp.asarray(keys, jnp.int32)
+    keys = to_device(keys, jnp.int32)
     n = keys.shape[0]
     tile = 256
     pad = (-n) % tile
@@ -160,4 +160,4 @@ def bloom_probe(filt, keys, *, k_hashes: int = 7, use_kernel: bool = True,
                            interpret=interpret)
     else:
         out = probe_ref(filt, keys, k_hashes)
-    return np.asarray(out[:n]).astype(bool)
+    return to_host(out[:n]).astype(bool)

@@ -14,8 +14,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...runtime import tracing
 from ..bloom.bloom import probe_filters_tiered
 from ..sizing import next_pow2
+from ..transfer import to_device, to_host
 from .merge import merge_tiles
 from .ref import merge_tiles_ref
 
@@ -92,18 +94,19 @@ def merge_sorted_runs(ka, va, kb, vb, *, tile: int = MERGE_TILE,
         keys2, vals2, _ = merge_tiles_ref(ka_t, va_t, kb_t, vb_t)
     keys = keys2[:, :tile].reshape(-1)      # first `tile` outputs are real
     vals = vals2[:, :tile].reshape(-1)
-    prev = jnp.concatenate([keys[:1] - 1, keys[:-1]])
-    keep = (keys != prev) & (keys != INT_MAX)
+    with jax.named_scope("keep_mask"):
+        prev = jnp.concatenate([keys[:1] - 1, keys[:-1]])
+        keep = (keys != prev) & (keys != INT_MAX)
     return keys, vals, keep
 
 
 def merge_runs_dedup(ka, va, kb, vb, **kw):
     """Host-friendly wrapper returning dense deduped numpy arrays."""
-    keys, vals, keep = merge_sorted_runs(jnp.asarray(ka, jnp.int32),
-                                         jnp.asarray(va, jnp.int32),
-                                         jnp.asarray(kb, jnp.int32),
-                                         jnp.asarray(vb, jnp.int32), **kw)
-    keys, vals, keep = map(np.asarray, (keys, vals, keep))
+    keys, vals, keep = merge_sorted_runs(to_device(ka, jnp.int32),
+                                         to_device(va, jnp.int32),
+                                         to_device(kb, jnp.int32),
+                                         to_device(vb, jnp.int32), **kw)
+    keys, vals, keep = map(to_host, (keys, vals, keep))
     return keys[keep], vals[keep]
 
 
@@ -143,6 +146,13 @@ def ingest_run(keys, src, *, tile: int = MERGE_TILE, use_kernel: bool = True,
 
 
 @jax.jit
+def search_sorted_run(keys, queries):
+    """Insertion position of each query in one sorted run: the per-table
+    search of the memory component and of the staged read path."""
+    return jnp.searchsorted(keys, queries)
+
+
+@jax.jit
 def _ranged_lookup(keys, vals, lo, hi, q):
     """Per-query lower-bound binary search of q[i] in keys[lo[i]:hi[i]]
     (each slice sorted), plus the hit test and payload gather -- one
@@ -170,9 +180,9 @@ def lookup_runs_device(keys, vals, lo, hi, queries):
     arrays, INT_MAX-padded). Queries are bucketed to a power of two
     (>= 256) with empty ranges so tiers sharing the (N, K-bucket) shape
     share the compiled search. Returns numpy (abs_pos, hit, val)."""
-    q = jnp.asarray(queries, jnp.int32)
-    lo = jnp.asarray(lo, jnp.int32)
-    hi = jnp.asarray(hi, jnp.int32)
+    q = to_device(queries, jnp.int32)
+    lo = to_device(lo, jnp.int32)
+    hi = to_device(hi, jnp.int32)
     n = q.shape[0]
     m = next_pow2(max(1, n), lo=256)
     if m > n:
@@ -181,9 +191,9 @@ def lookup_runs_device(keys, vals, lo, hi, queries):
         lo = jnp.concatenate([lo, z])
         hi = jnp.concatenate([hi, z])
     pos, hit, val = _ranged_lookup(keys, vals, lo, hi, q)
-    return (np.asarray(pos[:n]).astype(np.int64),
-            np.asarray(hit[:n]).astype(bool),
-            np.asarray(val[:n]).astype(np.int64))
+    return (to_host(pos[:n]).astype(np.int64),
+            to_host(hit[:n]).astype(bool),
+            to_host(val[:n]).astype(np.int64))
 
 
 @partial(jax.jit, static_argnames=("tier_of", "k_hashes", "btile",
@@ -197,46 +207,46 @@ def _store_probe(fstack, keys, vals, q, gti_t, ns_t, w_t, lo, hi, *,
     newest-wins tier argmin. Per tier, results are exactly what the
     per-tier fused pair (``probe_filters_multi`` + ``_ranged_lookup``)
     would produce."""
-    per_table = probe_filters_tiered(fstack.astype(jnp.int32), q,
-                                     gti_t, ns_t, w_t, k_hashes=k_hashes,
-                                     tile=btile,
-                                     interpret=interpret)    # [Tg, kpad]
     r, kpad = lo.shape
-    member = jax.ops.segment_sum(per_table,
-                                 jnp.asarray(tier_of, jnp.int32),
-                                 num_segments=r) > 0         # [R, kpad]
-    qf = jnp.broadcast_to(q[None, :], (r, kpad)).reshape(-1)
-    pos, hit, val = _ranged_lookup(keys, vals, lo.reshape(-1),
-                                   hi.reshape(-1), qf)
-    pos = pos.reshape(r, kpad)
-    hit = hit.reshape(r, kpad)
-    val = val.reshape(r, kpad)
+    with jax.named_scope("bloom_probe"):
+        per_table = probe_filters_tiered(fstack.astype(jnp.int32), q,
+                                         gti_t, ns_t, w_t,
+                                         k_hashes=k_hashes, tile=btile,
+                                         interpret=interpret)  # [Tg, kpad]
+        member = jax.ops.segment_sum(per_table,
+                                     jnp.asarray(tier_of, jnp.int32),
+                                     num_segments=r) > 0       # [R, kpad]
+    with jax.named_scope("ranged_search"):
+        qf = jnp.broadcast_to(q[None, :], (r, kpad)).reshape(-1)
+        pos, hit, val = _ranged_lookup(keys, vals, lo.reshape(-1),
+                                       hi.reshape(-1), qf)
+        pos = pos.reshape(r, kpad)
+        hit = hit.reshape(r, kpad)
+        val = val.reshape(r, kpad)
     # Newest-wins: the smallest tier rank whose probe hit, -1 when none
     # did (a hit implies a covering table, so ranking `hit` alone is the
     # staged path's first-resolving-tier order).
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (r, kpad), 0)
-    win = jnp.min(jnp.where(hit, ridx, r), axis=0)
-    return member, pos, hit, val, jnp.where(win < r, win, -1)
+    with jax.named_scope("newest_wins"):
+        ridx = jax.lax.broadcasted_iota(jnp.int32, (r, kpad), 0)
+        win = jnp.min(jnp.where(hit, ridx, r), axis=0)
+        win = jnp.where(win < r, win, -1)
+    return member, pos, hit, val, win
 
 
-def lookup_store_device(fstack, keys, vals, queries, gti, ns, w, lo, hi, *,
-                        tier_of: tuple, k_hashes: int = 7, btile: int = 256,
-                        interpret: bool = True):
-    """Store-sized fused cross-tier probe: ``queries`` against every
-    lookup tier of a tree in a single device launch.
+def store_probe_operands(queries, gti, ns, w, lo, hi, tier_of):
+    """The host half of the store-sized fused cross-tier probe:
+    ``queries`` against every lookup tier of a tree in a single device
+    launch (``run_store_probe``).
 
-    ``fstack`` [Tg*128, Wmax] stacks all tables of all tiers tier-major
-    (``tier_of``: global table index -> tier rank, static); ``keys``/
-    ``vals`` are the store-wide INT_MAX-padded concatenation. Per
-    (tier, query) metadata is [R, K]: ``gti`` the GLOBAL covering-table
-    index (clipped, as ``assign_bounds`` leaves it), ``ns``/``w`` that
-    table's filter geometry, ``lo``/``hi`` its run's span in the
-    concatenation. Queries bucket to a power of two (>= 256); padding
-    probes nothing (gti=-1) and searches nothing (lo=hi=0).
-
-    Returns numpy (member [R,K] bool, abs_pos [R,K], hit [R,K], val
-    [R,K], win [K]) with ``win`` the newest-wins tier rank (-1 = miss).
-    """
+    Per (tier, query) metadata is [R, K]: ``gti`` the GLOBAL
+    covering-table index (clipped, as ``assign_bounds`` leaves it),
+    ``ns``/``w`` that table's filter geometry, ``lo``/``hi`` its run's
+    span in the store-wide concatenation; ``tier_of`` maps each global
+    table to its tier rank. The metadata is expanded to the per-table
+    rows the kernel grids over, queries bucket to a power of two
+    (>= 256; padding probes nothing, gti=-1, and searches nothing,
+    lo=hi=0), and all of it is uploaded. Returns (device operands, real
+    query count)."""
     q = np.asarray(queries, np.int32)
     tmap = np.asarray(tier_of, np.int64)         # [Tg] table -> tier rank
     # Expand per-tier metadata to per-table rows (the constant-free block
@@ -260,16 +270,34 @@ def lookup_store_device(fstack, keys, vals, queries, gti, ns, w, lo, hi, *,
         zr = np.zeros((r_count, pad), np.int32)
         lo = np.concatenate([lo, zr], axis=1)
         hi = np.concatenate([hi, zr], axis=1)
-    member, pos, hit, val, win = _store_probe(
-        jnp.asarray(fstack), keys, vals, jnp.asarray(q),
-        jnp.asarray(gti_t), jnp.asarray(ns_t), jnp.asarray(w_t),
-        jnp.asarray(lo), jnp.asarray(hi), tier_of=tier_of,
-        k_hashes=k_hashes, btile=btile, interpret=interpret)
-    return (np.asarray(member[:, :n]).astype(bool),
-            np.asarray(pos[:, :n]).astype(np.int64),
-            np.asarray(hit[:, :n]).astype(bool),
-            np.asarray(val[:, :n]).astype(np.int64),
-            np.asarray(win[:n]).astype(np.int64))
+    return tuple(map(to_device, (q, gti_t, ns_t, w_t, lo, hi))), n
+
+
+@partial(jax.jit, static_argnames=("n",))
+def store_probe_results(member, pos, hit, val, win, *, n):
+    """The store probe's answers for the batch's ``n`` real queries."""
+    return member[:, :n], pos[:, :n], hit[:, :n], val[:, :n], win[:n]
+
+
+def run_store_probe(fstack, keys, vals, operands, n, *, tier_of: tuple,
+                    k_hashes: int = 7, btile: int = 256,
+                    interpret: bool = True):
+    """Dispatch ``_store_probe`` on ``store_probe_operands``' uploads and
+    pull its answers back (the ``read.probe_pull`` span).
+
+    ``fstack`` [Tg*128, Wmax] stacks all tables of all tiers tier-major
+    (``tier_of``, static); ``keys``/``vals`` are the store-wide
+    INT_MAX-padded concatenation. Returns numpy (member [R,K] bool,
+    abs_pos [R,K], hit [R,K], val [R,K], win [K]) with ``win`` the
+    newest-wins tier rank (-1 = miss)."""
+    out = _store_probe(to_device(fstack), keys, vals, *operands,
+                       tier_of=tier_of, k_hashes=k_hashes, btile=btile,
+                       interpret=interpret)
+    out = store_probe_results(*out, n=n)
+    with tracing.span("read.probe_pull"):
+        member, pos, hit, val, win = map(to_host, out)
+    return (member.astype(bool), pos.astype(np.int64), hit.astype(bool),
+            val.astype(np.int64), win.astype(np.int64))
 
 
 def merge_runs_device(runs, *, tile: int = MERGE_TILE, use_kernel: bool = True,
@@ -288,10 +316,12 @@ def merge_runs_device(runs, *, tile: int = MERGE_TILE, use_kernel: bool = True,
     if not rs:
         return np.empty(0, np.int32), np.empty(0, np.int32)
     ka, va = rs[0]
-    for kb, vb in rs[1:]:
-        ka_p, va_p = _pad_run(ka, va, next_pow2(ka.shape[0]))
-        kb_p, vb_p = _pad_run(kb, vb, next_pow2(kb.shape[0]))
-        ka, va = merge_runs_dedup(ka_p, va_p, kb_p, vb_p, tile=tile,
-                                  use_kernel=use_kernel,
-                                  interpret=interpret)
+    with tracing.span("merge.fold"):
+        tracing.count("merge.steps", len(rs) - 1)
+        for kb, vb in rs[1:]:
+            ka_p, va_p = _pad_run(ka, va, next_pow2(ka.shape[0]))
+            kb_p, vb_p = _pad_run(kb, vb, next_pow2(kb.shape[0]))
+            ka, va = merge_runs_dedup(ka_p, va_p, kb_p, vb_p, tile=tile,
+                                      use_kernel=use_kernel,
+                                      interpret=interpret)
     return ka, va

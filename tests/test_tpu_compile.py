@@ -96,7 +96,7 @@ def test_bloom_probe_multi_compiles(one_chip):
 
 
 def test_store_probe_compiles(one_chip):
-    """The one-launch cross-tier read behind ``lookup_store_device``: 64
+    """The one-launch cross-tier read behind ``run_store_probe``: 64
     tables in 4 tiers, a 256-query batch."""
     tiers, tables, k = 4, 64, 256
     tier_of = tuple(t * tiers // tables for t in range(tables))
