@@ -7,6 +7,10 @@ A backend supplies the engine's data-parallel primitives:
   * ``bloom_build(keys)``    -- per-SSTable Bloom filter construction
   * ``bloom_probe(f, keys)`` -- batched membership probes
   * ``lookup_batch(sorted_keys, queries)`` -- batched binary search in a run
+  * ``prepare_run(sorted_keys)`` / ``search_run(run, queries)`` -- the
+    same search against a run kept resident between calls (a memory
+    level of the partitioned memory component), so that each search
+    moves only its queries
   * ``prepare_tier(tables, bloom_fn)`` / ``lookup_fused(view, queries)``
     -- the device-resident read hot path: one fused Bloom-probe +
     sorted-probe pipeline over a whole disjoint tier of SSTables, replacing
@@ -82,6 +86,16 @@ class TierView:
     @property
     def num_entries(self) -> int:
         return int(self.offs[-1] + self.lens[-1]) if len(self.lens) else 0
+
+
+@dataclass
+class SortedRun:
+    """One sorted unique run kept for repeated searches (built by
+    ``ExecutionBackend.prepare_run``): its host keys, plus the backend's
+    resident copy, or None where the backend searches on the host."""
+
+    keys: np.ndarray
+    payload: object = None
 
 
 @dataclass
@@ -251,6 +265,15 @@ class ExecutionBackend:
         Returns (pos, found): the insertion position of each query (int64)
         and whether ``sorted_keys[pos] == query`` (bool).
         """
+        raise NotImplementedError
+
+    def prepare_run(self, sorted_keys) -> SortedRun:
+        """Keep a sorted unique run resident for ``search_run``."""
+        raise NotImplementedError
+
+    def search_run(self, run: SortedRun, queries):
+        """``lookup_batch(run.keys, queries)`` against a run prepared
+        once by ``prepare_run``: same contract, same results."""
         raise NotImplementedError
 
     def prepare_tier(self, tables, bloom_fn):

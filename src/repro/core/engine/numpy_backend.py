@@ -11,8 +11,8 @@ from __future__ import annotations
 import numpy as np
 
 from .backend import (BLOOM_K_HASHES, ExecutionBackend, FusedLookup,
-                      StoreLookup, StoreView, TierView, assign_bounds,
-                      bloom_sizing, register_backend)
+                      SortedRun, StoreLookup, StoreView, TierView,
+                      assign_bounds, bloom_sizing, register_backend)
 
 # Same int32 constants as kernels/bloom/ref.py (golden-ratio multipliers).
 C1 = np.int32(0x9E3779B1 - 2**32)
@@ -132,6 +132,13 @@ class NumpyBackend(ExecutionBackend):
         safe = np.minimum(pos, len(sorted_keys) - 1)
         found[inb] = sorted_keys[safe[inb]] == np.asarray(queries)[inb]
         return pos.astype(np.int64), found
+
+    def prepare_run(self, sorted_keys):
+        """The run stays on the host: nothing to copy."""
+        return SortedRun(np.asarray(sorted_keys))
+
+    def search_run(self, run, queries):
+        return self.lookup_batch(run.keys, queries)
 
     # -- fused tier probe ----------------------------------------------------
     def prepare_tier(self, tables, bloom_fn):

@@ -19,8 +19,9 @@ import numpy as np
 
 from ...runtime import tracing
 from .backend import (BLOOM_K_HASHES, ExecutionBackend, FusedLookup,
-                      StoreLookup, StoreView, TierView, assign_bounds,
-                      bloom_sizing, next_pow2, register_backend)
+                      SortedRun, StoreLookup, StoreView, TierView,
+                      assign_bounds, bloom_sizing, next_pow2,
+                      register_backend)
 from .numpy_backend import NumpyBackend, ingest_order
 
 _INT32_MAX = 2**31 - 1
@@ -168,24 +169,43 @@ class PallasBackend(ExecutionBackend):
                 and _int32_safe_keys([queries])):
             self.fallback_calls += 1
             return self._fallback.lookup_batch(sorted_keys, queries)
-        # Bucket both operands so the jitted searchsorted compiles once per
-        # (run, batch) size bucket: the run pads with an INT_MAX sentinel
-        # (never matched -- keys are int32-safe), queries pad by repeating
-        # their last element (results discarded).
-        n, q = len(sorted_keys), len(queries)
-        sk = np.pad(sorted_keys.astype(np.int32),
-                    (0, next_pow2(n) - n), constant_values=_INT32_MAX)
+        return self.search_run(self.prepare_run(sorted_keys), queries)
+
+    def prepare_run(self, sorted_keys):
+        """Pad the run once to a power of two with an INT_MAX sentinel
+        (never matched -- keys are int32-safe) and upload it. A run
+        outside the int32 domain stays on the host: ``search_run`` then
+        takes the numpy fallback and counts it."""
+        sorted_keys = np.asarray(sorted_keys)
+        if not _int32_safe_sorted(sorted_keys):
+            return SortedRun(sorted_keys)
+        n = len(sorted_keys)
+        sk = np.full(next_pow2(n), _INT32_MAX, np.int32)
+        sk[:n] = sorted_keys
+        return SortedRun(sorted_keys, self._transfer.to_device(sk))
+
+    def search_run(self, run, queries):
+        queries = np.asarray(queries)
+        if len(queries) == 0:
+            return np.zeros(0, np.int64), np.zeros(0, bool)
+        if run.payload is None or not _int32_safe_keys([queries]):
+            self.fallback_calls += 1
+            return self._fallback.lookup_batch(run.keys, queries)
+        # Queries pad to a power of two by repeating their last element
+        # (results discarded), so the jitted searchsorted compiles once
+        # per (run, batch) size bucket.
+        n, q = len(run.keys), len(queries)
         qk = np.pad(queries.astype(np.int32),
                     (0, next_pow2(q) - q), mode="edge")
         tr = self._transfer
         with self._note_jit():
             pos = tr.to_host(self._merge_ops.search_sorted_run(
-                tr.to_device(sk), tr.to_device(qk)))[:q]
+                run.payload, tr.to_device(qk)))[:q]
         pos = np.minimum(pos.astype(np.int64), n)
         inb = pos < n
         found = np.zeros(q, bool)
         safe = np.minimum(pos, n - 1)
-        found[inb] = sorted_keys[safe[inb]] == queries[inb]
+        found[inb] = run.keys[safe[inb]] == queries[inb]
         return pos, found
 
     # -- fused tier probe ----------------------------------------------------

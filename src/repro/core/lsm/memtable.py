@@ -19,8 +19,8 @@ import numpy as np
 
 from ...runtime import tracing
 from ..engine import get_backend
-from .sstable import (SSTable, partition_run, probe_tier,
-                      sstable_from_run)
+from ..engine.backend import SortedRun
+from .sstable import SSTable, partition_run, sstable_from_run
 
 
 @dataclass
@@ -117,8 +117,27 @@ def _overlap_slice(level, lo, hi):
     return i, j
 
 
+@dataclass
+class _LevelView:
+    """One memory level prepared for ``search_run``: the level's tables
+    are disjoint and min_key-sorted, so their concatenation is one
+    sorted run."""
+
+    tables: tuple                  # the level's SSTables, in level order
+    run: SortedRun
+    vals: np.ndarray               # int64 concatenation of the values
+
+
 class PartitionedMemComponent(MemComponentBase):
-    """§4.1.1: in-memory partitioned-leveling LSM-tree."""
+    """§4.1.1: in-memory partitioned-leveling LSM-tree.
+
+    Batched reads search each memory level once, through a view of the
+    whole level (``_LevelView``) that is rebuilt on the first read after
+    the level's table set changed. On a device backend the view keeps
+    the level's keys resident on the device: at most one int32 copy of
+    the keys of the write memory, which the memory tuner already counts
+    as write memory; it is not a buffer-cache page.
+    """
 
     def __init__(self, *, entry_bytes: int, page_bytes: int,
                  active_bytes_max: int, size_ratio: int = 10, backend=None):
@@ -132,6 +151,7 @@ class PartitionedMemComponent(MemComponentBase):
         self.levels: list[list[SSTable]] = []   # M1..Mk
         self.rr_key: int = -(2**62)       # round-robin flush cursor (by min_key)
         self.stats = MemStats()
+        self._views: list = []            # per level: _LevelView or None
 
     # -- bookkeeping ---------------------------------------------------------
     @property
@@ -344,7 +364,30 @@ class PartitionedMemComponent(MemComponentBase):
                     return True, val
         return False, 0
 
+    def _level_view(self, li: int, lvl: list) -> _LevelView:
+        """The view of level ``li``, rebuilt if its tables changed. Keyed
+        on the tables' identities (``SSTable`` compares by identity):
+        flushes, merges and a checkpoint restore all change them, however
+        they edit ``levels``."""
+        tables = tuple(lvl)
+        views = self._views
+        if li < len(views) and views[li] is not None \
+                and views[li].tables == tables:
+            return views[li]
+        with tracing.span("mem.view_build"):
+            view = _LevelView(
+                tables,
+                self.backend.prepare_run(
+                    np.concatenate([s.keys for s in tables])),
+                np.concatenate([s.vals for s in tables]))
+        tracing.count("mem.view_builds")
+        views.extend([None] * (li + 1 - len(views)))
+        views[li] = view
+        return view
+
     def lookup_batch(self, keys):
+        """M0's dict first, then one ``search_run`` per memory level,
+        newest first, with the keys still unresolved."""
         keys = np.asarray(keys, np.int64)
         n = len(keys)
         found = np.zeros(n, bool)
@@ -357,13 +400,20 @@ class PartitionedMemComponent(MemComponentBase):
                     if hit is not None:
                         found[i] = True
                         vals[i] = hit[0]
-            unresolved = ~found
+            del self._views[len(self.levels):]
+            idx = np.flatnonzero(~found)
             searched = 0
-            for lvl in self.levels:              # newest level first
-                if not unresolved.any():
+            for li, lvl in enumerate(self.levels):   # newest level first
+                if not len(idx):
                     break
-                searched += probe_tier(lvl, keys, found, vals, unresolved,
-                                       self.backend.lookup_batch)
+                if not lvl:
+                    continue
+                view = self._level_view(li, lvl)
+                pos, hit = self.backend.search_run(view.run, keys[idx])
+                searched += 1
+                found[idx[hit]] = True
+                vals[idx[hit]] = view.vals[pos[hit]]
+                idx = idx[~hit]
             tracing.count("mem.tables_searched", searched)
         return found, vals
 

@@ -79,10 +79,11 @@ def probe_tier(tables, keys, found, vals, unresolved, lookup_batch, *,
     """Probe one disjoint, sorted tier with every still-unresolved key,
     scattering hits into ``found``/``vals``/``unresolved`` in place.
 
-    The single home of the batched probe-and-scatter dance (vectorized
-    table assignment, per-table backend lookup, double-indexed hit
-    scatter) shared by the tree's disk tiers and the partitioned memory
-    component's levels. Hooks carry the disk-only concerns:
+    The staged read path of the tree's disk tiers: vectorized table
+    assignment, one backend lookup per table, double-indexed hit
+    scatter. (The partitioned memory component searches each of its
+    levels whole instead, ``PartitionedMemComponent.lookup_batch``.)
+    Hooks carry the disk-only concerns:
 
       pre_probe(sst, qk) -> bool mask of probes worth a binary search
         (the tree pins Bloom pages and probes the filter here);

@@ -147,8 +147,9 @@ def ingest_run(keys, src, *, tile: int = MERGE_TILE, use_kernel: bool = True,
 
 @jax.jit
 def search_sorted_run(keys, queries):
-    """Insertion position of each query in one sorted run: the per-table
-    search of the memory component and of the staged read path."""
+    """Insertion position of each query in one sorted run: the memory
+    component's search of one level, and the staged read path's search
+    of one table."""
     return jnp.searchsorted(keys, queries)
 
 

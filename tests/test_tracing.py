@@ -1,7 +1,8 @@
 """The program's recorder (``repro.runtime.tracing``): nothing recorded
 when off, parent links, submit numbers, counters and self time while
-recording, every span of the store on both backends, results and
-IOStats unchanged by recording, and record times that line up with the
+recording, every span of the store on both backends, the memory
+component's level views built once per change, results and IOStats
+unchanged by recording, and record times that line up with the
 profile's ``repro.*`` events."""
 import glob
 import os
@@ -112,8 +113,8 @@ def _drive(svc, submits=36, seed=0):
 
 
 COMMON = {"service.submit", "service.plan", "service.governor",
-          "mem.search", "read.pin_replay", "write.ingest", "tick.upkeep",
-          "tick.flush", "tick.merge", "tick.wal"}
+          "mem.search", "mem.view_build", "read.pin_replay", "write.ingest",
+          "tick.upkeep", "tick.flush", "tick.merge", "tick.wal"}
 DEVICE = {"read.probe_prep", "read.probe_pull", "merge.fold"}
 
 
@@ -143,6 +144,46 @@ def test_a_submit_records_every_span(backend):
         folds = [r for r in recs if r.name == "merge.fold"]
         assert sum(r.counts["merge.steps"] for r in folds) \
             == c["merge.steps"]
+
+
+@pytest.mark.parametrize("backend", ["numpy", "pallas"])
+def test_memory_levels_build_their_views_once_per_change(backend):
+    from repro.core.engine import get_backend
+    from repro.core.lsm.memtable import PartitionedMemComponent
+    reset_sst_ids()
+    mem = PartitionedMemComponent(entry_bytes=16, page_bytes=256,
+                                  active_bytes_max=1 * KB, size_ratio=2,
+                                  backend=get_backend(backend))
+    rng = np.random.default_rng(5)
+    for i in range(16):
+        ks = rng.integers(0, 4_000, 64)
+        mem.ingest_batch(ks, ks + i, i * KB)
+        mem.seal_active()
+        mem.maintain()
+    levels = sum(1 for lvl in mem.levels if lvl)
+    assert levels >= 3
+    absent = np.arange(10_000, 10_200)       # every level searches them
+    gets = 4
+    with tracing.recording():
+        for _ in range(gets):
+            mem.lookup_batch(absent)
+    c = tracing.counters()
+    assert c["mem.view_builds"] == levels
+    assert c["mem.tables_searched"] == gets * levels <= gets * len(mem.levels)
+    builds = [r for r in tracing.records() if r.name == "mem.view_build"]
+    assert len(builds) == levels
+    assert {tracing.records()[r.parent].name for r in builds} \
+        == {"mem.search"}
+    # a seal changes M1 alone: only its view is built again
+    tracing.clear()
+    mem.ingest_batch(np.array([5, 6]), np.array([1, 2]), 99 * KB)
+    mem.seal_active()
+    assert sum(1 for lvl in mem.levels if lvl) == levels
+    with tracing.recording():
+        found, _ = mem.lookup_batch(np.concatenate([absent, [5, 6]]))
+        mem.lookup_batch(absent)
+    assert found[-2:].all()
+    assert tracing.counters()["mem.view_builds"] == 1
 
 
 @pytest.mark.parametrize("backend", ["numpy", "pallas"])
