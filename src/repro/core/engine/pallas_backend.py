@@ -25,6 +25,20 @@ from .backend import (BLOOM_K_HASHES, ExecutionBackend, FusedLookup,
 from .numpy_backend import NumpyBackend, ingest_order
 
 _INT32_MAX = 2**31 - 1
+# A store view's filter stack holds a multiple of this many tables (zero
+# filters past the last), so stores whose table counts round alike share
+# one compiled store probe.
+STORE_TABLE_STEP = 64
+
+
+def _filter_stack(filts, tables: int, wmax: int) -> np.ndarray:
+    """The bool [tables*128, wmax] stack of the [128, W_t] filters
+    ``filts``, each zero-padded to ``wmax`` columns, zero rows past the
+    last filter."""
+    fstack = np.zeros((tables * 128, wmax), bool)
+    for i, f in enumerate(filts):
+        fstack[i * 128:(i + 1) * 128, :f.shape[1]] = f
+    return fstack
 
 
 def _int32_safe_keys(arrs) -> bool:
@@ -212,11 +226,13 @@ class PallasBackend(ExecutionBackend):
     def prepare_tier(self, tables, bloom_fn):
         """Device-resident tier view: the tier's key/val runs live on
         device as one INT_MAX-padded int32 concatenation, its Bloom
-        filters as one stacked [T*128, Wmax] array (the HBM pages a
-        ``DevicePagePool`` accounts for). Refuses (``None``) when any run
-        is outside the int32 kernel domain, when a table's filter came
-        from the numpy fallback, or when the widest filter would blow the
-        fused kernel's VMEM working set."""
+        filters as one stacked [T*128, Wmax] array, T the tier's table
+        count rounded up to a power of two so tiers of like size share
+        one compiled probe (the HBM pages a ``DevicePagePool`` accounts
+        for). Refuses (``None``) when any run is outside the int32
+        kernel domain, when a table's filter came from the numpy
+        fallback, or when the widest filter would blow the fused
+        kernel's VMEM working set."""
         keys_list = [t.keys for t in tables]
         if not (all(_int32_safe_sorted(k) for k in keys_list)
                 and _int32_safe_vals([t.vals for t in tables])):
@@ -233,9 +249,7 @@ class PallasBackend(ExecutionBackend):
         if wmax > self.fused_wmax:
             self.fallback_calls += 1
             return None
-        fstack = np.zeros((len(tables) * 128, wmax), bool)
-        for i, f in enumerate(filts):
-            fstack[i * 128:(i + 1) * 128, :f.shape[1]] = f
+        fstack = _filter_stack(filts, next_pow2(len(tables), lo=1), wmax)
         lens = np.array([t.num_entries for t in tables], np.int64)
         offs = np.concatenate([[0], np.cumsum(lens)[:-1]])
         total = int(lens.sum())
@@ -290,9 +304,13 @@ class PallasBackend(ExecutionBackend):
         """Device-resident view of EVERY lookup tier of one tree: all
         tables' key/val runs as one INT_MAX-padded int32 concatenation
         (tier-major), all Bloom filters as one stacked [Tg*128, Wmax]
-        array, plus the static global-table -> tier-rank map the fused
-        kernel grids over. Refusal conditions are the per-tier ones,
-        applied across the whole stack."""
+        array, plus the global-table -> tier-rank map the fused kernel
+        segment-sums over. The stack is padded to a multiple of
+        ``STORE_TABLE_STEP`` tables, so stores of like size and tier
+        count share one compiled probe (tiers are not padded: the ranged
+        search runs for every tier row of every query). Refusal
+        conditions are the per-tier ones, applied across the whole
+        stack."""
         tables = [t for tier in tiers for t in tier]
         if not (all(_int32_safe_sorted(t.keys) for t in tables)
                 and _int32_safe_vals([t.vals for t in tables])):
@@ -309,15 +327,16 @@ class PallasBackend(ExecutionBackend):
         if wmax > self.fused_wmax:
             self.fallback_calls += 1
             return None
-        fstack = np.zeros((len(tables) * 128, wmax), bool)
-        for i, f in enumerate(filts):
-            fstack[i * 128:(i + 1) * 128, :f.shape[1]] = f
+        t_pad = -(-max(1, len(tables)) // STORE_TABLE_STEP) \
+            * STORE_TABLE_STEP
+        fstack = _filter_stack(filts, t_pad, wmax)
         lens = np.array([t.num_entries for t in tables], np.int64)
         offs = (np.concatenate([[0], np.cumsum(lens)[:-1]])
                 if len(tables) else np.zeros(0, np.int64))
         counts = np.array([len(tier) for tier in tiers], np.int64)
         t_off = (np.concatenate([[0], np.cumsum(counts)[:-1]])
                  if len(tiers) else np.zeros(0, np.int64))
+        table_tier = np.repeat(np.arange(len(tiers)), counts)
         total = int(lens.sum())
         npad = next_pow2(max(1, total))
         ck = np.full(npad, _INT32_MAX, np.int32)
@@ -334,8 +353,11 @@ class PallasBackend(ExecutionBackend):
                                  np.int32),
             "w_t": np.array([f.shape[1] for f in filts], np.int32),
             "t_off": t_off,
-            "tier_of": tuple(r for r, tier in enumerate(tiers)
-                             for _ in tier),
+            "table_tier": table_tier,
+            "tier_of": up(np.concatenate(
+                [table_tier, np.full(t_pad - len(tables), len(tiers))])
+                .astype(np.int32)),
+            "tables": t_pad,
             "npad": npad,
         }
         return StoreView(
@@ -386,12 +408,11 @@ class PallasBackend(ExecutionBackend):
             gti = p["t_off"][:, None] + ti
             operands, n = ops.store_probe_operands(
                 q.astype(np.int32), gti, p["nslots_t"][gti],
-                p["w_t"][gti], lo, hi, p["tier_of"])
+                p["w_t"][gti], lo, hi, p["table_tier"], tables=p["tables"])
         with self._note_jit():
             member, abs_pos, hit, vals, win = ops.run_store_probe(
-                p["fstack"], p["keys"], p["vals"], operands, n,
-                tier_of=p["tier_of"], k_hashes=self.k_hashes,
-                interpret=self.interpret)
+                p["fstack"], p["keys"], p["vals"], p["tier_of"], operands,
+                n, k_hashes=self.k_hashes, interpret=self.interpret)
         return StoreLookup(ti=ti, ok=ok, positive=member,
                            pos=(abs_pos - lo).astype(np.int64),
                            hit=hit, vals=vals, win=win)

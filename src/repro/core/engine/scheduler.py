@@ -121,7 +121,14 @@ def rank_flush_victim(cands, policy):
     one-shard deployment bit-identical to a bare ``LSMStore``.
 
     Returns the chosen ``(store, tree)`` pair, or None if no candidates.
+    Recorded as one ``flush.pick`` span (attrs ``policy``,
+    ``candidates``).
     """
+    with tracing.span("flush.pick", policy=policy, candidates=len(cands)):
+        return _rank(cands, policy)
+
+
+def _rank(cands, policy):
     if not cands:
         return None
     if policy == "mem":
@@ -288,14 +295,20 @@ class MaintenanceScheduler(SegmentedScheduler):
 
         Only the cheap level bookkeeping settles here; the merge work the
         flush induces (L0 merges, level merges) accrues as merge debt and
-        is served by the budgeted merge pass."""
+        is served by the budgeted merge pass. Recorded as one
+        ``flush.tree`` span (attrs ``tree``, ``trigger`` and the
+        ``kind`` of flush taken)."""
         s = self.store
-        s._pre_flush_sample(tree)
-        freed = tree.flush(trigger=trigger, log_pos=s.log_pos,
-                           max_log_bytes=s.cfg.max_log_bytes,
-                           total_write_mem=s.write_memory_bytes,
-                           beta=s.cfg.beta, forced_kind=forced_kind)
-        tree.levels.adjust(s._tree_share(tree))
+        with tracing.span("flush.tree", tree=tree.name,
+                          trigger=trigger) as rec:
+            s._pre_flush_sample(tree)
+            kind, freed = tree.flush(trigger=trigger, log_pos=s.log_pos,
+                                     max_log_bytes=s.cfg.max_log_bytes,
+                                     total_write_mem=s.write_memory_bytes,
+                                     beta=s.cfg.beta, forced_kind=forced_kind)
+            tree.levels.adjust(s._tree_share(tree))
+            if rec is not None:
+                rec.attrs["kind"] = kind
         return freed
 
     def flush_dataset(self, ds: str, *, trigger: str) -> int:

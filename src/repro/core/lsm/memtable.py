@@ -4,8 +4,9 @@
 one LSM-tree is itself an in-memory partitioned-leveling LSM-tree — an active
 SSTable M0 plus memory levels M1..Mk of immutable, range-partitioned
 SSTables. It supports *partial* flushes (one last-level SSTable at a time,
-round-robin), min-LSN flushes (the SSTable with the smallest LSN plus all
-overlapping SSTables at newer levels, to facilitate log truncation), and
+round-robin), min-LSN flushes (the SSTable with the smallest LSN plus every
+SSTable of any level that overlaps its key range, to facilitate log
+truncation), and
 *full* flushes (merge-sort everything).
 
 Baseline components (monolithic B+-tree, Accordion) live in
@@ -309,32 +310,41 @@ class PartitionedMemComponent(MemComponentBase):
         return [(pick.keys, pick.vals, pick.lsn_min, pick.lsn_max)]
 
     def flush_min_lsn(self):
-        """§4.1.1 log-triggered: flush the min-LSN SSTable plus all
-        overlapping SSTables at newer (higher) levels, merged as one run."""
+        """§4.1.1 log-triggered: flush the min-LSN SSTable together with
+        every SSTable, at any memory level, that overlaps its key range,
+        closing over the range as the tables pulled in widen it; merged
+        newest level first into one run.
+
+        Closing over every level keeps the component's invariant that a
+        key's versions in memory are all newer than its versions on disk:
+        an older version left behind in a deeper level would shadow the
+        flushed one, and win over it once flushed into a newer L0 group."""
         if not any(self.levels):
             self.seal_active()
             self.maintain()
         if not any(self.levels):
             return []
-        best_li, best = None, None
-        for li, lvl in enumerate(self.levels):
-            for s in lvl:
-                if best is None or s.lsn_min < best.lsn_min:
-                    best_li, best = li, s
-        group = [best]
-        self.levels[best_li].remove(best)
-        for li in range(best_li - 1, -1, -1):   # newer levels
-            lvl = self.levels[li]
-            i, j = _overlap_slice(lvl, best.min_key, best.max_key)
-            group = lvl[i:j] + group            # newer first
+        best = min((s for lvl in self.levels for s in lvl),
+                   key=lambda s: s.lsn_min)
+        lo, hi = best.min_key, best.max_key
+        while True:
+            spans = [_overlap_slice(lvl, lo, hi) for lvl in self.levels]
+            picked = [s for lvl, (i, j) in zip(self.levels, spans)
+                      for s in lvl[i:j]]
+            wider = (min(s.min_key for s in picked),
+                     max(s.max_key for s in picked))
+            if wider == (lo, hi):
+                break
+            lo, hi = wider
+        for lvl, (i, j) in zip(self.levels, spans):
             del lvl[i:j]
         while self.levels and not self.levels[-1]:
             self.levels.pop()
         keys, vals = self.backend.merge_runs([(s.keys, s.vals)
-                                              for s in group])
-        self.stats.entries_merged += sum(s.num_entries for s in group)
-        return [(keys, vals, min(s.lsn_min for s in group),
-                 max(s.lsn_max for s in group))]
+                                              for s in picked])
+        self.stats.entries_merged += sum(s.num_entries for s in picked)
+        return [(keys, vals, min(s.lsn_min for s in picked),
+                 max(s.lsn_max for s in picked))]
 
     def flush_full(self):
         """§4.1.4: merge-sort the entire component into one sorted run."""

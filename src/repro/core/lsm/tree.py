@@ -138,9 +138,11 @@ class LSMTree:
     def _emit_flush(self, runs, *, trigger: str, log_pos: int) -> int:
         """Partition runs into disk SSTables, write them, insert into L0.
 
-        Returns bytes flushed.
+        Returns bytes flushed. Counts the entries installed in
+        ``flush.entries``, and the log-triggered ones in
+        ``flush.entries_log``.
         """
-        total = 0
+        total = entries = 0
         for keys, vals, lsn_min, lsn_max in runs:
             if len(keys) == 0:
                 continue
@@ -152,11 +154,14 @@ class LSMTree:
                 self.l0.insert(sst)
                 self._prepare_bloom(sst)
                 total += sst.size_bytes
+                entries += sst.num_entries
+        tracing.count("flush.entries", entries)
         if trigger == "mem":
             self.stats.bytes_flushed_mem += total
             self.disk.stats.bytes_flushed_mem += total
             self.disk.stats.flushes_mem += 1
         else:
+            tracing.count("flush.entries_log", entries)
             self.stats.bytes_flushed_log += total
             self.disk.stats.bytes_flushed_log += total
             self.disk.stats.flushes_log += 1
@@ -164,9 +169,12 @@ class LSMTree:
 
     def flush(self, *, trigger: str, log_pos: int, max_log_bytes: int,
               total_write_mem: int, beta: float = 0.5,
-              forced_kind: str | None = None) -> int:
+              forced_kind: str | None = None) -> tuple[str, int]:
         """Flush per §4.1: memory-triggered → partial round-robin; log-
-        triggered → adaptive partial(min-LSN)/full via the β window."""
+        triggered → adaptive partial(min-LSN)/full via the β window.
+        Returns the flush taken (``partial``, ``min_lsn`` or ``full``)
+        and the bytes flushed."""
+        kind = "full"               # monolithic components: always full
         if isinstance(self.mem, PartitionedMemComponent):
             if forced_kind is None:
                 if trigger == "mem":
@@ -181,22 +189,23 @@ class LSMTree:
                             else "full")
             else:
                 kind = forced_kind
-            if kind == "partial":
-                runs = (self.mem.flush_partial() if trigger == "mem"
-                        else self.mem.flush_min_lsn())
-            elif kind == "partial_rr":
-                runs = self.mem.flush_partial()
-            elif kind == "partial_oldest":
-                runs = self.mem.flush_min_lsn()
+            if kind == "partial_rr" or (kind == "partial"
+                                        and trigger == "mem"):
+                kind = "partial"
+            elif kind in ("partial", "partial_oldest"):
+                kind = "min_lsn"
             else:
-                runs = self.mem.flush_full()
-            flushed = self._emit_flush(runs, trigger=trigger, log_pos=log_pos)
-            if kind != "full" and flushed:
-                self.partial_flush_window.append((log_pos, flushed))
-            return flushed
-        # Monolithic components: always a full flush.
-        runs = self.mem.flush_full()
-        return self._emit_flush(runs, trigger=trigger, log_pos=log_pos)
+                kind = "full"
+        if kind == "partial":
+            runs = self.mem.flush_partial()
+        elif kind == "min_lsn":
+            runs = self.mem.flush_min_lsn()
+        else:
+            runs = self.mem.flush_full()
+        flushed = self._emit_flush(runs, trigger=trigger, log_pos=log_pos)
+        if kind != "full" and flushed:
+            self.partial_flush_window.append((log_pos, flushed))
+        return kind, flushed
 
     # -- merges (maintenance) -----------------------------------------------------
     def _merge_key(self, read):

@@ -4,9 +4,10 @@ execution backend (``StoreConfig(backend="pallas")``) calls."""
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from ..sizing import next_pow2, slots_for  # noqa: F401  (re-exported)
-from ..transfer import to_device, to_host
+from ..transfer import padded, to_device, to_host
 from .bloom import (build_filter, probe_filter, probe_filters_multi,
                     probe_filters_tiered)
 from .ref import build_ref, probe_multi_ref, probe_ref, probe_tiered_ref
@@ -38,7 +39,7 @@ def bloom_build_run(keys, *, n_keys_padded: int | None = None,
     filter at exactly ``n_slots``, so an engine that buckets run sizes
     reuses compiled kernels across SSTables of similar size.
     """
-    keys = to_device(keys, jnp.int32)
+    keys = np.asarray(keys, np.int32)
     n = keys.shape[0]
     assert n >= 1, "empty key set"
     if n_keys_padded is None:
@@ -47,9 +48,8 @@ def bloom_build_run(keys, *, n_keys_padded: int | None = None,
         n_slots = slots_for(n_keys_padded, bits_per_key)
     tile = 256
     total = -(-max(n_keys_padded, n) // tile) * tile
-    if total > n:
-        keys = jnp.concatenate(
-            [keys, jnp.broadcast_to(keys[:1], (total - n,))])
+    # Padded on the host, so only the bucketed shape reaches the device.
+    keys = to_device(np.pad(keys, (0, total - n), constant_values=keys[0]))
     if use_kernel:
         return build_filter(keys, n_slots=n_slots, k_hashes=k_hashes,
                             interpret=interpret)
@@ -66,17 +66,15 @@ def bloom_probe_run(filt, keys, *, k_hashes: int = 7,
     on-device, so only the 1-byte representation crosses the host boundary.
     """
     filt = to_device(filt).astype(jnp.int32)
-    keys = to_device(keys, jnp.int32)
-    n = keys.shape[0]
+    n = len(keys)
     m = next_pow2(max(1, n), lo=256)
-    if m > n:
-        keys = jnp.concatenate([keys, jnp.zeros((m - n,), jnp.int32)])
+    keys = to_device(padded(keys, (m,)))
     if use_kernel:
         out = probe_filter(filt, keys, k_hashes=k_hashes,
                            interpret=interpret)
     else:
         out = probe_ref(filt, keys, k_hashes)
-    return to_host(out[:n]).astype(bool)
+    return to_host(out)[:n].astype(bool)
 
 
 def bloom_probe_multi(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
@@ -88,24 +86,16 @@ def bloom_probe_multi(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
     the same (T, Wmax, K-bucket) share compiled kernels.
     """
     fstack = to_device(fstack).astype(jnp.int32)
-    keys = to_device(keys, jnp.int32)
-    ti = to_device(ti, jnp.int32)
-    nslots = to_device(nslots, jnp.int32)
-    w = to_device(w, jnp.int32)
-    n = keys.shape[0]
+    n = len(keys)
     m = next_pow2(max(1, n), lo=256)
-    if m > n:
-        keys = jnp.concatenate([keys, jnp.zeros((m - n,), jnp.int32)])
-        ti = jnp.concatenate([ti, jnp.full((m - n,), -1, jnp.int32)])
-        nslots = jnp.concatenate([nslots,
-                                  jnp.full((m - n,), 128, jnp.int32)])
-        w = jnp.concatenate([w, jnp.ones((m - n,), jnp.int32)])
+    keys, ti, nslots, w = (to_device(padded(a, (m,), fill)) for a, fill in
+                           ((keys, 0), (ti, -1), (nslots, 128), (w, 1)))
     if use_kernel:
         out = probe_filters_multi(fstack, keys, ti, nslots, w,
                                   k_hashes=k_hashes, interpret=interpret)
     else:
         out = probe_multi_ref(fstack, keys, ti, nslots, w, k_hashes)
-    return to_host(out[:n]).astype(bool)
+    return to_host(out)[:n].astype(bool)
 
 
 def bloom_probe_tiered(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,

@@ -17,7 +17,7 @@ import numpy as np
 from ...runtime import tracing
 from ..bloom.bloom import probe_filters_tiered
 from ..sizing import next_pow2
-from ..transfer import to_device, to_host
+from ..transfer import padded, to_device, to_host
 from .merge import merge_tiles
 from .ref import merge_tiles_ref
 
@@ -175,47 +175,56 @@ def _ranged_lookup(keys, vals, lo, hi, q):
     return pos, hit, jnp.where(hit, vals[safe], 0)
 
 
+def _table_rows(per_tier, tmap, tables: int, m: int, fill: int):
+    """Per-tier rows ``per_tier`` [R, K] expanded to the per-table rows
+    [tables, m] the store probe grids over: row t is its tier's row
+    (``tmap``), padded with ``fill``. Written in one pass, since the
+    [tables, m] arrays are the largest host work of a lookup batch."""
+    src = padded(per_tier, (len(per_tier), m), fill)
+    out = np.empty((tables, m), np.int32)
+    np.take(src, tmap, axis=0, out=out[:len(tmap)])
+    out[len(tmap):] = fill
+    return out
+
+
 def lookup_runs_device(keys, vals, lo, hi, queries):
     """Run-sized fused sorted probe: ``queries[i]`` against the sorted
     slice ``keys[lo[i]:hi[i]]`` of a tier's concatenated runs (device
     arrays, INT_MAX-padded). Queries are bucketed to a power of two
-    (>= 256) with empty ranges so tiers sharing the (N, K-bucket) shape
-    share the compiled search. Returns numpy (abs_pos, hit, val)."""
-    q = to_device(queries, jnp.int32)
-    lo = to_device(lo, jnp.int32)
-    hi = to_device(hi, jnp.int32)
-    n = q.shape[0]
+    (>= 256) with empty ranges, padded on the host, so tiers sharing the
+    (N, K-bucket) shape share the compiled search. Returns numpy
+    (abs_pos, hit, val)."""
+    n = len(queries)
     m = next_pow2(max(1, n), lo=256)
-    if m > n:
-        z = jnp.zeros((m - n,), jnp.int32)
-        q = jnp.concatenate([q, z])
-        lo = jnp.concatenate([lo, z])
-        hi = jnp.concatenate([hi, z])
-    pos, hit, val = _ranged_lookup(keys, vals, lo, hi, q)
-    return (to_host(pos[:n]).astype(np.int64),
-            to_host(hit[:n]).astype(bool),
-            to_host(val[:n]).astype(np.int64))
+    q, lo, hi = (to_device(padded(a, (m,))) for a in (queries, lo, hi))
+    pos, hit, val = map(to_host, _ranged_lookup(keys, vals, lo, hi, q))
+    return (pos[:n].astype(np.int64), hit[:n].astype(bool),
+            val[:n].astype(np.int64))
 
 
-@partial(jax.jit, static_argnames=("tier_of", "k_hashes", "btile",
-                                   "interpret"))
-def _store_probe(fstack, keys, vals, q, gti_t, ns_t, w_t, lo, hi, *,
-                 tier_of, k_hashes, btile, interpret):
+@partial(jax.jit, static_argnames=("k_hashes", "btile", "interpret"))
+def _store_probe(fstack, keys, vals, q, gti_t, ns_t, w_t, lo, hi, tier_of,
+                 *, k_hashes, btile, interpret):
     """The whole cross-tier read in ONE jitted invocation: the stacked
     tiered Bloom probe (per-table rows, segment-summed into per-tier
     membership by ``tier_of``), the ranged sorted probe of every
     (tier, query) pair over the store-wide concatenation, and the
     newest-wins tier argmin. Per tier, results are exactly what the
     per-tier fused pair (``probe_filters_multi`` + ``_ranged_lookup``)
-    would produce."""
+    would produce.
+
+    The tier map ``tier_of`` [Tg] is an operand, not part of the
+    program: a table row past the store's last tier (padding) belongs
+    to no tier. So the program depends only on the sizes (padded tables,
+    tiers, padded entries, filter width, padded queries), and trees and
+    layouts that bucket alike share it."""
     r, kpad = lo.shape
     with jax.named_scope("bloom_probe"):
         per_table = probe_filters_tiered(fstack.astype(jnp.int32), q,
                                          gti_t, ns_t, w_t,
                                          k_hashes=k_hashes, tile=btile,
                                          interpret=interpret)  # [Tg, kpad]
-        member = jax.ops.segment_sum(per_table,
-                                     jnp.asarray(tier_of, jnp.int32),
+        member = jax.ops.segment_sum(per_table, tier_of,
                                      num_segments=r) > 0       # [R, kpad]
     with jax.named_scope("ranged_search"):
         qf = jnp.broadcast_to(q[None, :], (r, kpad)).reshape(-1)
@@ -234,7 +243,8 @@ def _store_probe(fstack, keys, vals, q, gti_t, ns_t, w_t, lo, hi, *,
     return member, pos, hit, val, win
 
 
-def store_probe_operands(queries, gti, ns, w, lo, hi, tier_of):
+def store_probe_operands(queries, gti, ns, w, lo, hi, table_tier, *,
+                         tables: int):
     """The host half of the store-sized fused cross-tier probe:
     ``queries`` against every lookup tier of a tree in a single device
     launch (``run_store_probe``).
@@ -242,63 +252,43 @@ def store_probe_operands(queries, gti, ns, w, lo, hi, tier_of):
     Per (tier, query) metadata is [R, K]: ``gti`` the GLOBAL
     covering-table index (clipped, as ``assign_bounds`` leaves it),
     ``ns``/``w`` that table's filter geometry, ``lo``/``hi`` its run's
-    span in the store-wide concatenation; ``tier_of`` maps each global
-    table to its tier rank. The metadata is expanded to the per-table
-    rows the kernel grids over, queries bucket to a power of two
-    (>= 256; padding probes nothing, gti=-1, and searches nothing,
-    lo=hi=0), and all of it is uploaded. Returns (device operands, real
-    query count)."""
-    q = np.asarray(queries, np.int32)
-    tmap = np.asarray(tier_of, np.int64)         # [Tg] table -> tier rank
-    # Expand per-tier metadata to per-table rows (the constant-free block
-    # layout the kernel grids over): row t repeats its tier's row.
-    gti_t = np.asarray(gti, np.int32)[tmap]
-    ns_t = np.asarray(ns, np.int32)[tmap]
-    w_t = np.asarray(w, np.int32)[tmap]
-    lo = np.asarray(lo, np.int32)
-    hi = np.asarray(hi, np.int32)
-    r_count = lo.shape[0]
-    t_count = len(tier_of)
-    n = q.shape[0]
+    span in the store-wide concatenation; ``table_tier`` maps each
+    global table to its tier rank. The metadata is expanded to the
+    per-table rows the kernel grids over, padded to ``tables`` rows (a
+    padding row probes nothing, gti=-1); queries bucket to a power of
+    two (>= 256; padding probes nothing, gti=-1, and searches nothing,
+    lo=hi=0). All of it is padded on the host and uploaded. Returns
+    (device operands, real query count)."""
+    tmap = np.asarray(table_tier, np.int64)      # [Tg] table -> tier rank
+    n = len(queries)
     m = next_pow2(max(1, n), lo=256)
-    if m > n:
-        pad = m - n
-        q = np.concatenate([q, np.zeros(pad, np.int32)])
-        zt = np.zeros((t_count, pad), np.int32)
-        gti_t = np.concatenate([gti_t, zt - 1], axis=1)
-        ns_t = np.concatenate([ns_t, zt + 128], axis=1)
-        w_t = np.concatenate([w_t, zt + 1], axis=1)
-        zr = np.zeros((r_count, pad), np.int32)
-        lo = np.concatenate([lo, zr], axis=1)
-        hi = np.concatenate([hi, zr], axis=1)
-    return tuple(map(to_device, (q, gti_t, ns_t, w_t, lo, hi))), n
+    ops = (padded(queries, (m,)),
+           _table_rows(gti, tmap, tables, m, -1),
+           _table_rows(ns, tmap, tables, m, 128),
+           _table_rows(w, tmap, tables, m, 1),
+           padded(lo, (len(lo), m)), padded(hi, (len(hi), m)))
+    return tuple(map(to_device, ops)), n
 
 
-@partial(jax.jit, static_argnames=("n",))
-def store_probe_results(member, pos, hit, val, win, *, n):
-    """The store probe's answers for the batch's ``n`` real queries."""
-    return member[:, :n], pos[:, :n], hit[:, :n], val[:, :n], win[:n]
-
-
-def run_store_probe(fstack, keys, vals, operands, n, *, tier_of: tuple,
+def run_store_probe(fstack, keys, vals, tier_of, operands, n, *,
                     k_hashes: int = 7, btile: int = 256,
                     interpret: bool = True):
     """Dispatch ``_store_probe`` on ``store_probe_operands``' uploads and
-    pull its answers back (the ``read.probe_pull`` span).
+    pull its answers back (the ``read.probe_pull`` span), cut on the
+    host to the ``n`` real queries.
 
     ``fstack`` [Tg*128, Wmax] stacks all tables of all tiers tier-major
-    (``tier_of``, static); ``keys``/``vals`` are the store-wide
-    INT_MAX-padded concatenation. Returns numpy (member [R,K] bool,
-    abs_pos [R,K], hit [R,K], val [R,K], win [K]) with ``win`` the
-    newest-wins tier rank (-1 = miss)."""
-    out = _store_probe(to_device(fstack), keys, vals, *operands,
-                       tier_of=tier_of, k_hashes=k_hashes, btile=btile,
-                       interpret=interpret)
-    out = store_probe_results(*out, n=n)
+    (``tier_of`` [Tg], a device array, gives each row's tier rank);
+    ``keys``/``vals`` are the store-wide INT_MAX-padded concatenation.
+    Returns numpy (member [R,K] bool, abs_pos [R,K], hit [R,K], val
+    [R,K], win [K]) with ``win`` the newest-wins tier rank (-1 = miss)."""
+    out = _store_probe(fstack, keys, vals, *operands, tier_of,
+                       k_hashes=k_hashes, btile=btile, interpret=interpret)
     with tracing.span("read.probe_pull"):
         member, pos, hit, val, win = map(to_host, out)
-    return (member.astype(bool), pos.astype(np.int64), hit.astype(bool),
-            val.astype(np.int64), win.astype(np.int64))
+    return (member[:, :n].astype(bool), pos[:, :n].astype(np.int64),
+            hit[:, :n].astype(bool), val[:, :n].astype(np.int64),
+            win[:n].astype(np.int64))
 
 
 def merge_runs_device(runs, *, tile: int = MERGE_TILE, use_kernel: bool = True,

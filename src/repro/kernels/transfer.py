@@ -22,3 +22,15 @@ def to_host(x) -> np.ndarray:
     """``np.asarray(x)`` of a device array, its download counted."""
     tracing.count("d2h_bytes", x.nbytes)
     return np.asarray(x)
+
+
+def padded(a, shape, fill=0) -> np.ndarray:
+    """``a`` as int32 in the leading corner of an int32 array of
+    ``shape`` filled with ``fill``. Kernel entry points pad operands to
+    their bucketed shape on the host before the upload, so the device
+    sees bucketed shapes only and no padding program compiles per
+    length."""
+    a = np.asarray(a, np.int32)
+    out = np.full(shape, fill, np.int32)
+    out[tuple(slice(0, d) for d in a.shape)] = a
+    return out

@@ -400,6 +400,26 @@ def test_jit_shape_cache_counters():
     assert st["jit_cache_hits"] == pb.jit_cache_hits
 
 
+def test_store_probe_compiles_once_per_size_bucket():
+    """Store views of other layouts (tables per tier) in the same size
+    bucket, and batches of other query counts in the same power of two,
+    reuse the compiled store probe: the tier map is an operand, and
+    padding and cutting happen on the host."""
+    pb = PallasBackend(interpret=True)
+    rng = np.random.default_rng(5)
+    reset_sst_ids()
+    bloom = lambda s: pb.bloom_build(s.keys)                # noqa: E731
+    views = [pb.prepare_store([make_tier(rng, a), make_tier(rng, b)], bloom)
+             for a, b in ((2, 3), (4, 1), (1, 2))]
+    q = rng.integers(0, 200_000, 400).astype(np.int64)
+    pb.lookup_store_fused(views[0], q)
+    c, h = pb.jit_compiles, pb.jit_cache_hits
+    for view in views[1:]:
+        for n in (300, 400, 511):
+            assert pb.lookup_store_fused(view, q[:n]) is not None
+    assert pb.jit_compiles == c and pb.jit_cache_hits == h + 6
+
+
 def test_memory_plan_actuates_device_pool_budget():
     class PinPool(MemoryGovernor):
         def __init__(self, budget):

@@ -99,12 +99,11 @@ def test_store_probe_compiles(one_chip):
     """The one-launch cross-tier read behind ``run_store_probe``: 64
     tables in 4 tiers, a 256-query batch."""
     tiers, tables, k = 4, 64, 256
-    tier_of = tuple(t * tiers // tables for t in range(tables))
     w = bloom_sizing(SST_KEYS)[1] // 128
     npad = next_pow2(tables * SST_KEYS)
-    _compile(lambda f, ks, vs, q, g, n, w_, lo, hi: _store_probe(
-                 f, ks, vs, q, g, n, w_, lo, hi, tier_of=tier_of,
+    _compile(lambda f, ks, vs, q, g, n, w_, lo, hi, t: _store_probe(
+                 f, ks, vs, q, g, n, w_, lo, hi, t,
                  k_hashes=7, btile=256, interpret=False),
              one_chip, ((tables * 128, w), jnp.bool_), ((npad,), I32),
              ((npad,), I32), ((k,), I32), *[((tables, k), I32)] * 3,
-             *[((tiers, k), I32)] * 2)
+             *[((tiers, k), I32)] * 2, ((tables,), I32))

@@ -114,7 +114,8 @@ def _drive(svc, submits=36, seed=0):
 
 COMMON = {"service.submit", "service.plan", "service.governor",
           "mem.search", "mem.view_build", "read.pin_replay", "write.ingest",
-          "tick.upkeep", "tick.flush", "tick.merge", "tick.wal"}
+          "tick.upkeep", "tick.flush", "tick.merge", "tick.wal",
+          "flush.pick", "flush.tree"}
 DEVICE = {"read.probe_prep", "read.probe_pull", "merge.fold"}
 
 
@@ -138,6 +139,11 @@ def test_a_submit_records_every_span(backend):
             assert r.submit == by_index[r.parent].submit
     c = tracing.counters()
     assert c["mem.tables_searched"] > 0
+    assert c["flush.entries"] > 0 and "flush.entries_log" not in c
+    flushes = [r for r in recs if r.name == "flush.tree"]
+    assert {by_index[r.parent].name for r in flushes} == {"tick.flush"}
+    assert sum(r.counts["flush.entries"] for r in flushes) \
+        == c["flush.entries"]
     if backend == "pallas":
         assert c["h2d_bytes"] > 0 and c["d2h_bytes"] > 0
         assert c["merge.steps"] > 0
