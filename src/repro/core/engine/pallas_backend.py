@@ -41,6 +41,18 @@ def _filter_stack(filts, tables: int, wmax: int) -> np.ndarray:
     return fstack
 
 
+def _slot_stack(filts, tables: int) -> np.ndarray:
+    """The bool [tables, max slots] stack of the [128, W_t] filters
+    ``filts``, each flattened to slot order (slot = row * W_t + col, the
+    numpy backend's flat filter), zero past its slots and in the rows
+    past the last filter."""
+    fslots = np.zeros((tables, max((f.size for f in filts), default=128)),
+                      bool)
+    for i, f in enumerate(filts):
+        fslots[i, :f.size] = f.reshape(-1)
+    return fslots
+
+
 def _int32_safe_keys(arrs) -> bool:
     return all(len(a) == 0 or (int(a.min()) >= 0
                                and int(a.max()) < _INT32_MAX)
@@ -303,14 +315,13 @@ class PallasBackend(ExecutionBackend):
     def prepare_store(self, tiers, bloom_fn):
         """Device-resident view of EVERY lookup tier of one tree: all
         tables' key/val runs as one INT_MAX-padded int32 concatenation
-        (tier-major), all Bloom filters as one stacked [Tg*128, Wmax]
-        array, plus the global-table -> tier-rank map the fused kernel
-        segment-sums over. The stack is padded to a multiple of
-        ``STORE_TABLE_STEP`` tables, so stores of like size and tier
-        count share one compiled probe (tiers are not padded: the ranged
-        search runs for every tier row of every query). Refusal
-        conditions are the per-tier ones, applied across the whole
-        stack."""
+        (tier-major), all Bloom filters as one bool [Tg, slots] stack in
+        slot order, plus the ranged search's halvings for the longest
+        table. The stack is padded to a multiple of ``STORE_TABLE_STEP``
+        tables, so stores of like size and tier count share one compiled
+        probe (tiers are not padded: the probe runs for every tier row
+        of every query). Refusal conditions are the per-tier ones,
+        applied across the whole stack."""
         tables = [t for tier in tiers for t in tier]
         if not (all(_int32_safe_sorted(t.keys) for t in tables)
                 and _int32_safe_vals([t.vals for t in tables])):
@@ -329,14 +340,12 @@ class PallasBackend(ExecutionBackend):
             return None
         t_pad = -(-max(1, len(tables)) // STORE_TABLE_STEP) \
             * STORE_TABLE_STEP
-        fstack = _filter_stack(filts, t_pad, wmax)
         lens = np.array([t.num_entries for t in tables], np.int64)
         offs = (np.concatenate([[0], np.cumsum(lens)[:-1]])
                 if len(tables) else np.zeros(0, np.int64))
         counts = np.array([len(tier) for tier in tiers], np.int64)
         t_off = (np.concatenate([[0], np.cumsum(counts)[:-1]])
                  if len(tiers) else np.zeros(0, np.int64))
-        table_tier = np.repeat(np.arange(len(tiers)), counts)
         total = int(lens.sum())
         npad = next_pow2(max(1, total))
         ck = np.full(npad, _INT32_MAX, np.int32)
@@ -348,16 +357,10 @@ class PallasBackend(ExecutionBackend):
         payload = {
             "keys": up(ck),
             "vals": up(cv),
-            "fstack": up(fstack),
-            "nslots_t": np.array([128 * f.shape[1] for f in filts],
-                                 np.int32),
-            "w_t": np.array([f.shape[1] for f in filts], np.int32),
+            "fslots": up(_slot_stack(filts, t_pad)),
+            "nslots_t": np.array([f.size for f in filts], np.int32),
             "t_off": t_off,
-            "table_tier": table_tier,
-            "tier_of": up(np.concatenate(
-                [table_tier, np.full(t_pad - len(tables), len(tiers))])
-                .astype(np.int32)),
-            "tables": t_pad,
+            "steps": self._merge_ops.search_steps(lens.max(initial=1)),
             "npad": npad,
         }
         return StoreView(
@@ -375,11 +378,12 @@ class PallasBackend(ExecutionBackend):
 
     def lookup_store_fused(self, view, queries):
         """ONE device launch for the whole store: the composed
-        ``_store_probe`` jit fuses the stacked Bloom probe, the
-        cross-tier ranged sorted probe, and the newest-wins tier argmin,
-        in place of the per-tier fused path's two launches *per tier*.
-        The host work before the launch is the ``read.probe_prep``
-        span."""
+        ``_store_probe`` jit fuses the Bloom probe and the ranged sorted
+        probe of each (tier, query)'s covering table, and the
+        newest-wins tier argmin, in place of the per-tier fused path's
+        two launches *per tier*. The host work before the launch is the
+        ``read.probe_prep`` span; ``read.filter_probes`` counts the
+        (tier, query) pairs with a covering table."""
         q = np.asarray(queries)
         if not _int32_safe_keys([q]):
             self.fallback_calls += 1
@@ -405,14 +409,14 @@ class PallasBackend(ExecutionBackend):
                                              view.tier_ends[r], q64)
                 lo[r] = view.tier_offs[r][ti[r]]
                 hi[r] = lo[r] + view.tier_lens[r][ti[r]]
+            tracing.count("read.filter_probes", int(ok.sum()))
             gti = p["t_off"][:, None] + ti
             operands, n = ops.store_probe_operands(
-                q.astype(np.int32), gti, p["nslots_t"][gti],
-                p["w_t"][gti], lo, hi, p["table_tier"], tables=p["tables"])
+                q.astype(np.int32), gti, p["nslots_t"][gti], lo, hi)
         with self._note_jit():
             member, abs_pos, hit, vals, win = ops.run_store_probe(
-                p["fstack"], p["keys"], p["vals"], p["tier_of"], operands,
-                n, k_hashes=self.k_hashes, interpret=self.interpret)
+                p["fslots"], p["keys"], p["vals"], operands, n,
+                steps=p["steps"], k_hashes=self.k_hashes)
         return StoreLookup(ti=ti, ok=ok, positive=member,
                            pos=(abs_pos - lo).astype(np.int64),
                            hit=hit, vals=vals, win=win)

@@ -137,54 +137,6 @@ def probe_filters_multi(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
     return out.reshape(-1)
 
 
-def _probe_tiered_kernel(keys_ref, ti_ref, ns_ref, w_ref, filt_ref, out_ref,
-                         *, k_hashes):
-    """Cross-tier twin of ``_probe_multi_kernel``: grid step (i, t) probes
-    query tile i against *global* table t's filter block and writes table
-    t's own output row -- each (t, i) block is visited exactly once, so no
-    accumulation is needed (and the index maps stay constant-free, a
-    Pallas requirement). The caller segment-sums table rows into tier
-    rows."""
-    t = pl.program_id(1)
-    member = _member(filt_ref[...], keys_ref[...], ns_ref[...], w_ref[...],
-                     k_hashes)
-    out_ref[...] = (member & (ti_ref[...] == t)).astype(jnp.int32)
-
-
-@partial(jax.jit, static_argnames=("k_hashes", "tile", "interpret"))
-def probe_filters_tiered(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
-                         tile: int = 256, interpret: bool = True):
-    """fstack [Tg*128, Wmax]: ALL tables of ALL tiers of a store, stacked
-    tier-major. keys [K]; ti/nslots/w are per (table, query) [Tg, K]: row
-    t carries the GLOBAL covering-table index (and its filter geometry)
-    that *t's tier* assigned each query (-1 = none, never a member).
-    Returns int32 [Tg, K]: out[t, q] = 1 iff table t is q's assigned
-    table in its tier AND the filter reports membership -- tier
-    membership is the segment-sum of its tables' rows. One grid
-    (K/tile, Tg), the same total step count as per-tier
-    ``probe_filters_multi`` sweeps over every tier, collapsed into ONE
-    launch; VMEM still holds one [128, Wmax] filter block per step.
-    Per-table rows run through the kernel as [Tg, 1, K] so a one-row
-    block's last two dims equal the array's."""
-    k = keys.shape[0]
-    assert k % tile == 0 and fstack.shape[0] % 128 == 0
-    t_count = fstack.shape[0] // 128
-    assert ti.shape[0] == t_count
-    wmax = fstack.shape[1]
-    row_of = pl.BlockSpec((None, 1, tile), lambda i, t: (t, 0, i))
-    out = pl.pallas_call(
-        partial(_probe_tiered_kernel, k_hashes=k_hashes),
-        grid=(k // tile, t_count),
-        in_specs=[pl.BlockSpec((1, tile), lambda i, t: (0, i)),
-                  row_of, row_of, row_of,
-                  pl.BlockSpec((128, wmax), lambda i, t: (t, 0))],
-        out_specs=row_of,
-        out_shape=jax.ShapeDtypeStruct((t_count, 1, k), jnp.int32),
-        interpret=interpret,
-    )(keys.reshape(1, -1), ti[:, None], nslots[:, None], w[:, None], fstack)
-    return out.reshape(t_count, k)
-
-
 @partial(jax.jit, static_argnames=("k_hashes", "tile", "interpret"))
 def probe_filter(filt, keys, *, k_hashes: int = 7, tile: int = 256,
                  interpret: bool = True):

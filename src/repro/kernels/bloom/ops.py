@@ -8,9 +8,8 @@ import numpy as np
 
 from ..sizing import next_pow2, slots_for  # noqa: F401  (re-exported)
 from ..transfer import padded, to_device, to_host
-from .bloom import (build_filter, probe_filter, probe_filters_multi,
-                    probe_filters_tiered)
-from .ref import build_ref, probe_multi_ref, probe_ref, probe_tiered_ref
+from .bloom import build_filter, probe_filter, probe_filters_multi
+from .ref import build_ref, probe_multi_ref, probe_ref
 
 
 def bloom_build(keys, *, bits_per_key: int = 10, k_hashes: int = 7,
@@ -96,45 +95,6 @@ def bloom_probe_multi(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
     else:
         out = probe_multi_ref(fstack, keys, ti, nslots, w, k_hashes)
     return to_host(out)[:n].astype(bool)
-
-
-def bloom_probe_tiered(fstack, keys, ti, nslots, w, *, k_hashes: int = 7,
-                       use_kernel: bool = True, interpret: bool = True):
-    """Cross-tier fused probe: every query against its assigned table in
-    EVERY tier of a store, one device invocation for the whole stack.
-
-    ``fstack`` [Tg*128, Wmax] holds all tables of all tiers tier-major.
-    ``keys`` [K]; ``ti``/``nslots``/``w`` [Tg, K] per (table, query) --
-    row t carries the GLOBAL covering-table index (and geometry) that
-    t's tier assigned each query (-1 = none, never a member). Queries
-    are bucketed to a power of two (>= 256). Returns a bool [Tg, K]
-    per-table matrix; a tier's membership is the OR over its tables'
-    rows.
-    """
-    fstack = to_device(fstack).astype(jnp.int32)
-    keys = to_device(keys, jnp.int32)
-    ti = to_device(ti, jnp.int32)
-    nslots = to_device(nslots, jnp.int32)
-    w = to_device(w, jnp.int32)
-    t_count = ti.shape[0]
-    n = keys.shape[0]
-    m = next_pow2(max(1, n), lo=256)
-    if m > n:
-        pad = m - n
-        keys = jnp.concatenate([keys, jnp.zeros((pad,), jnp.int32)])
-        ti = jnp.concatenate(
-            [ti, jnp.full((t_count, pad), -1, jnp.int32)], axis=1)
-        nslots = jnp.concatenate(
-            [nslots, jnp.full((t_count, pad), 128, jnp.int32)], axis=1)
-        w = jnp.concatenate(
-            [w, jnp.ones((t_count, pad), jnp.int32)], axis=1)
-    if use_kernel:
-        out = probe_filters_tiered(fstack, keys, ti, nslots, w,
-                                   k_hashes=k_hashes,
-                                   interpret=interpret)
-    else:
-        out = probe_tiered_ref(fstack, keys, ti, nslots, w, k_hashes)
-    return to_host(out[:, :n]).astype(bool)
 
 
 def bloom_probe(filt, keys, *, k_hashes: int = 7, use_kernel: bool = True,

@@ -38,26 +38,6 @@ def probe_ref(filt, keys, k_hashes: int = 7):
     return jnp.all(vals > 0, axis=-1).astype(jnp.int32)
 
 
-def probe_tiered_ref(fstack, keys, ti, nslots, w, k_hashes: int = 7):
-    """Cross-tier oracle for ``probe_filters_tiered``. ``keys`` [K];
-    ``ti``/``nslots``/``w`` [Tg, K] per (table, query), ``ti`` the
-    *global* assigned-table index of each table's tier (-1 = none).
-    out[t, q] = 1 iff ``ti[t, q] == t`` and table t's filter reports
-    membership; tier membership is the segment-sum over its tables."""
-    keys = keys.astype(jnp.int32)
-    h1 = (keys[None, :] * C1) % nslots
-    h2 = ((keys[None, :] * C2) | 1) % nslots
-    j = jnp.arange(k_hashes, dtype=jnp.int32)
-    slots = (h1[..., None] + j * h2[..., None]) % nslots[..., None]
-    row = ti[..., None] * 128 + slots // w[..., None]
-    col = slots % w[..., None]
-    safe = jnp.clip(row, 0, fstack.shape[0] - 1)
-    vals = fstack[safe, col]
-    rows = jnp.arange(ti.shape[0], dtype=ti.dtype)[:, None]
-    return (jnp.all(vals > 0, axis=-1)
-            & (ti == rows)).astype(jnp.int32)
-
-
 def probe_multi_ref(fstack, keys, ti, nslots, w, k_hashes: int = 7):
     """Fused multi-filter oracle: probe each key against *its own* table's
     filter in a stack of T filters.

@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...runtime import tracing
-from ..bloom.bloom import probe_filters_tiered
+from ..bloom.ref import C1, C2
 from ..sizing import next_pow2
 from ..transfer import padded, to_device, to_host
 from .merge import merge_tiles
@@ -153,12 +153,15 @@ def search_sorted_run(keys, queries):
     return jnp.searchsorted(keys, queries)
 
 
-@jax.jit
-def _ranged_lookup(keys, vals, lo, hi, q):
+@partial(jax.jit, static_argnames=("steps",))
+def _ranged_lookup(keys, vals, lo, hi, q, steps: int = 32):
     """Per-query lower-bound binary search of q[i] in keys[lo[i]:hi[i]]
     (each slice sorted), plus the hit test and payload gather -- one
     fused device invocation for a whole tier of concatenated runs.
-    Same vectorized open-interval scheme as ``_diag_splits``."""
+    Same vectorized open-interval scheme as ``_diag_splits``. Each step
+    at least halves every open range, so ``steps`` halvings settle a
+    range of up to 2**steps - 1 entries; the default 32 covers any
+    int32 span."""
     n = keys.shape[0]
 
     def body(_, lohi):
@@ -169,22 +172,10 @@ def _ranged_lookup(keys, vals, lo, hi, q):
         return (jnp.where(open_ & less, mid + 1, lo),
                 jnp.where(open_ & ~less, mid, hi))
 
-    pos, _ = jax.lax.fori_loop(0, 32, body, (lo, hi))
+    pos, _ = jax.lax.fori_loop(0, steps, body, (lo, hi))
     safe = jnp.clip(pos, 0, max(n - 1, 0))
     hit = (pos < hi) & (keys[safe] == q)   # hi: the original range end
     return pos, hit, jnp.where(hit, vals[safe], 0)
-
-
-def _table_rows(per_tier, tmap, tables: int, m: int, fill: int):
-    """Per-tier rows ``per_tier`` [R, K] expanded to the per-table rows
-    [tables, m] the store probe grids over: row t is its tier's row
-    (``tmap``), padded with ``fill``. Written in one pass, since the
-    [tables, m] arrays are the largest host work of a lookup batch."""
-    src = padded(per_tier, (len(per_tier), m), fill)
-    out = np.empty((tables, m), np.int32)
-    np.take(src, tmap, axis=0, out=out[:len(tmap)])
-    out[len(tmap):] = fill
-    return out
 
 
 def lookup_runs_device(keys, vals, lo, hi, queries):
@@ -202,34 +193,54 @@ def lookup_runs_device(keys, vals, lo, hi, queries):
             val[:n].astype(np.int64))
 
 
-@partial(jax.jit, static_argnames=("k_hashes", "btile", "interpret"))
-def _store_probe(fstack, keys, vals, q, gti_t, ns_t, w_t, lo, hi, tier_of,
-                 *, k_hashes, btile, interpret):
-    """The whole cross-tier read in ONE jitted invocation: the stacked
-    tiered Bloom probe (per-table rows, segment-summed into per-tier
-    membership by ``tier_of``), the ranged sorted probe of every
-    (tier, query) pair over the store-wide concatenation, and the
-    newest-wins tier argmin. Per tier, results are exactly what the
-    per-tier fused pair (``probe_filters_multi`` + ``_ranged_lookup``)
-    would produce.
+def search_steps(max_entries: int) -> int:
+    """Halvings the ranged search needs for tables of up to
+    ``max_entries`` entries, the length bucketed to a power of two so
+    views of like size share one compiled store probe."""
+    return next_pow2(int(max_entries), lo=1).bit_length()
 
-    The tier map ``tier_of`` [Tg] is an operand, not part of the
-    program: a table row past the store's last tier (padding) belongs
-    to no tier. So the program depends only on the sizes (padded tables,
-    tiers, padded entries, filter width, padded queries), and trees and
-    layouts that bucket alike share it."""
+
+def _filter_bits(fslots, q, gti, ns, k_hashes):
+    """Bloom membership of each query in table ``gti`` (-1: none, never
+    a member): the double-hash slots of ``kernels/bloom/ref.py``, each
+    hash one gather from row ``gti`` of the slot-ordered stack. Indexing
+    by slot keeps the per-query ``// w`` and ``% w`` of the [128, W]
+    layout out of the gathers' indices: with them, compiling the store
+    probe for a v5e took about 24 s instead of under 2 s."""
+    h1 = (q * C1) % ns
+    h2 = ((q * C2) | 1) % ns
+    row = jnp.maximum(gti, 0)
+    member = gti >= 0
+    for j in range(k_hashes):
+        member &= fslots[row, (h1 + j * h2) % ns]
+    return member
+
+
+@partial(jax.jit, static_argnames=("k_hashes", "steps"))
+def _store_probe(fslots, keys, vals, q, gti, ns, lo, hi, *, k_hashes,
+                 steps):
+    """The whole cross-tier read in ONE jitted invocation, each
+    (tier, query) pair doing only the work of its covering table: the
+    k filter bits of that table gathered from the resident stack, the
+    ranged sorted probe of its run in the store-wide concatenation
+    (``steps`` halvings, static), and the newest-wins tier argmin. Per
+    tier, results are exactly what the per-tier fused pair
+    (``probe_filters_multi`` + ``_ranged_lookup``) would produce.
+
+    ``fslots`` [Tg, S] holds table t's filter bits in slot order (slot
+    = row * W_t + col of its [128, W_t] filter), zero past its slots.
+    ``gti``/``ns``/``lo``/``hi`` are per (tier, query) [R, kpad]
+    operands, so the program depends only on the sizes (padded tables,
+    slots, tiers, padded entries, padded queries, ``steps``), and trees
+    and layouts that bucket alike share it."""
     r, kpad = lo.shape
+    qf = jnp.broadcast_to(q[None, :], (r, kpad)).reshape(-1)
     with jax.named_scope("bloom_probe"):
-        per_table = probe_filters_tiered(fstack.astype(jnp.int32), q,
-                                         gti_t, ns_t, w_t,
-                                         k_hashes=k_hashes, tile=btile,
-                                         interpret=interpret)  # [Tg, kpad]
-        member = jax.ops.segment_sum(per_table, tier_of,
-                                     num_segments=r) > 0       # [R, kpad]
+        member = _filter_bits(fslots, qf, gti.reshape(-1), ns.reshape(-1),
+                              k_hashes).reshape(r, kpad)
     with jax.named_scope("ranged_search"):
-        qf = jnp.broadcast_to(q[None, :], (r, kpad)).reshape(-1)
         pos, hit, val = _ranged_lookup(keys, vals, lo.reshape(-1),
-                                       hi.reshape(-1), qf)
+                                       hi.reshape(-1), qf, steps=steps)
         pos = pos.reshape(r, kpad)
         hit = hit.reshape(r, kpad)
         val = val.reshape(r, kpad)
@@ -243,47 +254,40 @@ def _store_probe(fstack, keys, vals, q, gti_t, ns_t, w_t, lo, hi, tier_of,
     return member, pos, hit, val, win
 
 
-def store_probe_operands(queries, gti, ns, w, lo, hi, table_tier, *,
-                         tables: int):
+def store_probe_operands(queries, gti, ns, lo, hi):
     """The host half of the store-sized fused cross-tier probe:
     ``queries`` against every lookup tier of a tree in a single device
     launch (``run_store_probe``).
 
     Per (tier, query) metadata is [R, K]: ``gti`` the GLOBAL
     covering-table index (clipped, as ``assign_bounds`` leaves it),
-    ``ns``/``w`` that table's filter geometry, ``lo``/``hi`` its run's
-    span in the store-wide concatenation; ``table_tier`` maps each
-    global table to its tier rank. The metadata is expanded to the
-    per-table rows the kernel grids over, padded to ``tables`` rows (a
-    padding row probes nothing, gti=-1); queries bucket to a power of
-    two (>= 256; padding probes nothing, gti=-1, and searches nothing,
-    lo=hi=0). All of it is padded on the host and uploaded. Returns
-    (device operands, real query count)."""
-    tmap = np.asarray(table_tier, np.int64)      # [Tg] table -> tier rank
+    ``ns`` that table's filter slot count, ``lo``/``hi`` its run's span
+    in the store-wide concatenation. Queries bucket to a power of two
+    (>= 256); a padding query probes nothing (gti=-1) and searches
+    nothing (lo=hi=0). All of it is padded on the host and uploaded.
+    Returns (device operands, real query count)."""
     n = len(queries)
     m = next_pow2(max(1, n), lo=256)
-    ops = (padded(queries, (m,)),
-           _table_rows(gti, tmap, tables, m, -1),
-           _table_rows(ns, tmap, tables, m, 128),
-           _table_rows(w, tmap, tables, m, 1),
-           padded(lo, (len(lo), m)), padded(hi, (len(hi), m)))
+    r = len(gti)
+    ops = (padded(queries, (m,)), padded(gti, (r, m), -1),
+           padded(ns, (r, m), 128), padded(lo, (r, m)), padded(hi, (r, m)))
     return tuple(map(to_device, ops)), n
 
 
-def run_store_probe(fstack, keys, vals, tier_of, operands, n, *,
-                    k_hashes: int = 7, btile: int = 256,
-                    interpret: bool = True):
+def run_store_probe(fslots, keys, vals, operands, n, *, steps: int,
+                    k_hashes: int = 7):
     """Dispatch ``_store_probe`` on ``store_probe_operands``' uploads and
     pull its answers back (the ``read.probe_pull`` span), cut on the
     host to the ``n`` real queries.
 
-    ``fstack`` [Tg*128, Wmax] stacks all tables of all tiers tier-major
-    (``tier_of`` [Tg], a device array, gives each row's tier rank);
-    ``keys``/``vals`` are the store-wide INT_MAX-padded concatenation.
-    Returns numpy (member [R,K] bool, abs_pos [R,K], hit [R,K], val
-    [R,K], win [K]) with ``win`` the newest-wins tier rank (-1 = miss)."""
-    out = _store_probe(fstack, keys, vals, *operands, tier_of,
-                       k_hashes=k_hashes, btile=btile, interpret=interpret)
+    ``fslots`` [Tg, S] bool holds all tables of all tiers tier-major,
+    each table's filter in slot order; ``keys``/``vals`` are the
+    store-wide INT_MAX-padded concatenation; ``steps`` is
+    ``search_steps`` of its longest table. Returns numpy (member [R,K]
+    bool, abs_pos [R,K], hit [R,K], val [R,K], win [K]) with ``win`` the
+    newest-wins tier rank (-1 = miss)."""
+    out = _store_probe(fslots, keys, vals, *operands, k_hashes=k_hashes,
+                       steps=steps)
     with tracing.span("read.probe_pull"):
         member, pos, hit, val, win = map(to_host, out)
     return (member[:, :n].astype(bool), pos[:, :n].astype(np.int64),

@@ -152,6 +152,74 @@ def test_store_fused_empty_and_all_miss(backends):
         assert (r1.win == -1).all() and not r1.hit.any(), b.name
 
 
+def _tier_of(rng, sizes, key_lo, key_hi):
+    """A lookup tier whose tables hold ``sizes`` entries, keys drawn from
+    [key_lo, key_hi): tables of other sizes get Bloom filters of other
+    widths."""
+    keys = np.sort(rng.choice(np.arange(key_lo, key_hi), sum(sizes),
+                              replace=False)).astype(np.int64)
+    vals = rng.integers(1, 2**30, len(keys)).astype(np.int64)
+    cuts = np.cumsum([0] + list(sizes))
+    return [partition_run(keys[a:b], vals[a:b], 0, 0, 256, 4 * KB,
+                          (b - a) * 256)[0]
+            for a, b in zip(cuts[:-1], cuts[1:])]
+
+
+def _store_probe_case(case, rng):
+    """(tiers, queries) of one case of the store probe's differential."""
+    if case == "empty":
+        return [], rng.integers(0, 1000, 37).astype(np.int64)
+    if case == "pow2":
+        # The longest table holds exactly 2**11 entries: queries at both
+        # ends of every table take the most halvings the search makes
+        # (its second key, the 12th).
+        tiers = [_tier_of(rng, [300, 90], 0, 50_000),
+                 _tier_of(rng, [2048, 2048, 1000], 0, 200_000)]
+        ends = np.concatenate([[t.min_key - 1, t.min_key, t.min_key + 1,
+                                t.keys[1], t.keys[-2], t.max_key,
+                                t.max_key + 1]
+                               for tier in tiers for t in tier])
+        return tiers, np.concatenate(
+            [ends, rng.integers(0, 200_000, 200)]).astype(np.int64)
+    # Uneven tiers of unlike filter widths over unlike key ranges, so a
+    # query may have no covering table in a tier, in a batch of 300 that
+    # pads to 512.
+    tiers = [_tier_of(rng, [120], 40_000, 60_000),
+             _tier_of(rng, [700, 260, 700, 1300], 0, 150_000),
+             _tier_of(rng, [1500] * 6 + [31], 20_000, 300_000)]
+    allk = np.concatenate([t.keys for tier in tiers for t in tier])
+    return tiers, np.concatenate(
+        [rng.choice(allk, 200), rng.integers(0, 320_000, 100)]
+    ).astype(np.int64)
+
+
+@pytest.mark.parametrize("case", ["uneven", "pow2", "empty"])
+def test_store_probe_matches_numpy(backends, case):
+    """The Pallas store probe (one Bloom gather and one ranged search of
+    each (tier, query)'s covering table) against the numpy backend's
+    ``lookup_store_fused``, field for field, and its
+    ``read.filter_probes`` count: the (tier, query) pairs with a
+    covering table."""
+    from repro.runtime import tracing
+    nb, pb = backends
+    reset_sst_ids()
+    tiers, q = _store_probe_case(case, np.random.default_rng(16))
+    ref = nb.lookup_store_fused(
+        nb.prepare_store(tiers, lambda s: nb.bloom_build(s.keys)), q)
+    view = pb.prepare_store(tiers, lambda s: pb.bloom_build(s.keys))
+    assert view is not None
+    c0 = tracing.counters().get("read.filter_probes", 0)
+    with tracing.recording():
+        r = pb.lookup_store_fused(view, q)
+    for fld in ("ti", "ok", "positive", "pos", "hit", "vals", "win"):
+        np.testing.assert_array_equal(getattr(r, fld), getattr(ref, fld),
+                                      err_msg=f"{case} field={fld}")
+    assert tracing.counters().get("read.filter_probes", 0) - c0 \
+        == int(r.ok.sum())
+    if case != "empty":
+        assert r.hit.any() and (~r.ok).any() and r.ok.any()
+
+
 def test_fused_refuses_out_of_domain(backends):
     """Out-of-int32 tiers/queries return None (staged fallback), never
     wrong results."""
@@ -403,7 +471,8 @@ def test_jit_shape_cache_counters():
 def test_store_probe_compiles_once_per_size_bucket():
     """Store views of other layouts (tables per tier) in the same size
     bucket, and batches of other query counts in the same power of two,
-    reuse the compiled store probe: the tier map is an operand, and
+    reuse the compiled store probe: the per-tier rows are operands, the
+    search's halvings follow the longest table's power of two, and
     padding and cutting happen on the host."""
     pb = PallasBackend(interpret=True)
     rng = np.random.default_rng(5)

@@ -22,7 +22,8 @@ import pytest
 from repro.core.engine.backend import bloom_sizing
 from repro.kernels.bloom.bloom import (build_filter, probe_filter,
                                        probe_filters_multi)
-from repro.kernels.merge.ops import _store_probe, merge_sorted_runs
+from repro.kernels.merge.ops import (_store_probe, merge_sorted_runs,
+                                     search_steps)
 from repro.kernels.sizing import next_pow2
 
 SST_KEYS = 2048                          # 2 MB SSTable / 1 KB entries
@@ -49,10 +50,13 @@ def one_chip():
     cc.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, kernel=True):
+    """Compile ``fn`` for the described chip; ``kernel``: the program
+    must hold a Pallas kernel."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text, "the Pallas kernel became plain XLA"
+    if kernel:
+        assert "tpu_custom_call" in text, "the Pallas kernel became plain XLA"
 
 
 I32 = jnp.int32
@@ -97,13 +101,15 @@ def test_bloom_probe_multi_compiles(one_chip):
 
 def test_store_probe_compiles(one_chip):
     """The one-launch cross-tier read behind ``run_store_probe``: 64
-    tables in 4 tiers, a 256-query batch."""
+    tables in 4 tiers, a 256-query batch, per-tier operands and the
+    ranged search's halvings for tables of ``SST_KEYS`` entries. Its
+    Bloom half is a gather from the slot-ordered stack, not a kernel."""
     tiers, tables, k = 4, 64, 256
-    w = bloom_sizing(SST_KEYS)[1] // 128
+    slots = bloom_sizing(SST_KEYS)[1]
     npad = next_pow2(tables * SST_KEYS)
-    _compile(lambda f, ks, vs, q, g, n, w_, lo, hi, t: _store_probe(
-                 f, ks, vs, q, g, n, w_, lo, hi, t,
-                 k_hashes=7, btile=256, interpret=False),
-             one_chip, ((tables * 128, w), jnp.bool_), ((npad,), I32),
-             ((npad,), I32), ((k,), I32), *[((tables, k), I32)] * 3,
-             *[((tiers, k), I32)] * 2, ((tables,), I32))
+    _compile(lambda f, ks, vs, q, g, n, lo, hi: _store_probe(
+                 f, ks, vs, q, g, n, lo, hi,
+                 k_hashes=7, steps=search_steps(SST_KEYS)),
+             one_chip, ((tables, slots), jnp.bool_), ((npad,), I32),
+             ((npad,), I32), ((k,), I32), *[((tiers, k), I32)] * 4,
+             kernel=False)
