@@ -13,10 +13,10 @@ scatters across layers:
   * the §4.2 flush-policy selection -- any governor may switch the store's
     flush policy through ``MemoryPlan.flush_policy``.
 
-``StaticGovernor`` pins a fixed allocation (the baseline schemes). New
-policies implement ``observe`` -- e.g. the serving runtime's
-``repro.runtime.hbm_tuner.HBMGovernor`` drives the KV-pool / prefix-cache
-HBM split through this same interface.
+``StaticGovernor`` pins a fixed allocation (the baseline schemes),
+``DevicePoolGovernor`` sizes the device page pool behind fused lookups and
+``StallGovernor`` tunes the maintenance pacer. New policies implement
+``observe``.
 """
 from __future__ import annotations
 

@@ -1,1 +1,1 @@
-# TPU Pallas kernels: merge (compaction), bloom (point lookups), attention (prefill).
+# TPU Pallas kernels: merge (compaction), bloom (point lookups).

@@ -12,7 +12,7 @@ cost(x) = ω * offload(x) + γ * recompute(x)   [page-transfers per op]
 
 Newton–Raphson step + clamps reuse repro.core.tuner.tuner.newton_step
 verbatim — the white-box machinery is identical, only the cost sources
-changed (DESIGN.md §2).
+changed.
 """
 from __future__ import annotations
 
@@ -20,11 +20,6 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from ..core.service.governor import (DevicePoolGovernor,  # noqa: F401
-                                     MemoryGovernor, MemoryPlan)
-# DevicePoolGovernor moved to core/service/governor.py (it is a
-# storage-service policy, not a serving-runtime tuner); re-exported
-# here for existing importers.
 from ..core.tuner.tuner import TunerConfig, newton_step
 from .kvcache import PagedKVPool
 
@@ -91,35 +86,3 @@ class HBMTuner:
         self._last = dict(st)
         return rec
 
-
-class HBMGovernor(MemoryGovernor):
-    """The HBM split behind the storage-service governor interface: the
-    same ``observe() -> MemoryPlan`` contract the LSM ``StorageService``
-    uses, driving the KV-pool / prefix-cache boundary instead of write
-    memory / buffer cache. Serving loops call ``observe`` per decode step
-    (see ``repro.runtime.serving.greedy_generate``).
-
-    ``device_pool_bytes`` optionally rides along: an HBM governor that
-    also owns a store's fused-read page pool emits the budget through the
-    plan's ``device_pool_bytes`` field -- the exact actuation path
-    ``StorageService._apply_plan`` already runs for the serving split."""
-
-    def __init__(self, pool: PagedKVPool, cfg: HBMTunerConfig | None = None,
-                 *, device_pool_bytes: int | None = None):
-        self.tuner = HBMTuner(pool, cfg)
-        self.device_pool_bytes = device_pool_bytes
-
-    @property
-    def records(self):
-        return self.tuner.records
-
-    def observe(self, service=None) -> MemoryPlan | None:
-        rec = self.tuner.maybe_tune()
-        if rec is None:
-            return None
-        # The tuner actuates set_pool_pages itself; the plan only reports
-        # the decision. write_memory_bytes stays None -- the quantity here
-        # is POOL PAGES, and populating the byte field would make a
-        # StorageService mis-actuate it as an LSM write-memory size.
-        return MemoryPlan(device_pool_bytes=self.device_pool_bytes,
-                          note=f"hbm-pool-pages:{int(rec['x_next'])}")

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from repro.core.lsm.sstable import reset_sst_ids
-from repro.core.lsm.storage import LSMStore, StoreConfig
+from repro.core.lsm.storage import POLICIES, SCHEMES, LSMStore, StoreConfig
 from repro.core.service import (Deferred, Delete, Get, Put, Scan,
                                 ServiceConfig, StorageService)
 from repro.core.tuner.tuner import AdaptiveMemoryController, TunerConfig
@@ -185,8 +185,7 @@ def fingerprint(store):
 
 # --------------------------- fixed-seed suite ---------------------------------
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
-@pytest.mark.parametrize("scheme", ["partitioned", "btree-dynamic",
-                                    "accordion-data"])
+@pytest.mark.parametrize("scheme", SCHEMES)
 def test_batched_vs_scalar_bit_identical(seed, scheme):
     ops = gen_ops(np.random.default_rng(seed))
     s_b, out_b, _ = replay(ops, batched=True, scheme=scheme)
@@ -197,20 +196,25 @@ def test_batched_vs_scalar_bit_identical(seed, scheme):
     assert vars(s_b.disk.stats) == vars(s_s.disk.stats)
 
 
-@pytest.mark.parametrize("policy", ["mem", "opt"])
-def test_batched_vs_scalar_across_policies(policy):
+# "lsn" is replay's default, which test_batched_vs_scalar_bit_identical runs
+@pytest.mark.parametrize("scheme", SCHEMES)
+@pytest.mark.parametrize("policy", [p for p in POLICIES if p != "lsn"])
+def test_batched_vs_scalar_across_policies(policy, scheme):
     ops = gen_ops(np.random.default_rng(7), n_ops=12)
-    s_b, out_b, _ = replay(ops, batched=True, policy=policy)
-    s_s, out_s, _ = replay(ops, batched=False, policy=policy)
+    s_b, out_b, _ = replay(ops, batched=True, scheme=scheme, policy=policy)
+    s_s, out_s, _ = replay(ops, batched=False, scheme=scheme, policy=policy)
     assert out_b == out_s
     assert fingerprint(s_b) == fingerprint(s_s)
 
 
+@pytest.mark.parametrize("scheme", SCHEMES)
+@pytest.mark.parametrize("policy", POLICIES)
 @pytest.mark.parametrize("batched", [True, False])
-def test_numpy_vs_pallas_bit_identical(batched):
+def test_numpy_vs_pallas_bit_identical(batched, policy, scheme):
     ops = gen_ops(np.random.default_rng(5), n_ops=8)
-    s_n, out_n, _ = replay(ops, backend="numpy", batched=batched)
-    s_p, out_p, _ = replay(ops, backend="pallas", batched=batched)
+    kw = dict(batched=batched, scheme=scheme, policy=policy)
+    s_n, out_n, _ = replay(ops, backend="numpy", **kw)
+    s_p, out_p, _ = replay(ops, backend="pallas", **kw)
     assert out_n == out_p
     assert fingerprint(s_n) == fingerprint(s_p)
     assert vars(s_n.disk.stats) == vars(s_p.disk.stats)
@@ -411,7 +415,9 @@ def test_service_submit_matches_direct_calls(backend, seed):
     assert vars(svc.store.disk.stats) == vars(store.disk.stats)
 
 
-@pytest.mark.parametrize("scheme", ["btree-dynamic", "accordion-data"])
+# "partitioned" is small_config's default, which
+# test_service_submit_matches_direct_calls runs
+@pytest.mark.parametrize("scheme", [s for s in SCHEMES if s != "partitioned"])
 def test_service_submit_matches_direct_calls_schemes(scheme):
     batches = gen_request_batches(np.random.default_rng(23), n_batches=6)
     reset_sst_ids()

@@ -9,7 +9,7 @@ from repro.core.engine import (NumpyBackend, PallasBackend, bloom_sizing,
                                get_backend)
 from repro.core.lsm.cache import ClockCache, Disk
 from repro.core.lsm.sstable import reset_sst_ids, sstable_from_run
-from repro.core.lsm.storage import LSMStore, StoreConfig
+from repro.core.lsm.storage import SCHEMES, LSMStore, StoreConfig
 
 KB, MB = 1 << 10, 1 << 20
 
@@ -175,8 +175,7 @@ def test_read_batch_matches_scalar_lookup_loop():
             assert v == oracle[k]
 
 
-@pytest.mark.parametrize("scheme", ["partitioned", "btree-dynamic",
-                                    "accordion-data"])
+@pytest.mark.parametrize("scheme", SCHEMES)
 def test_store_end_to_end_pallas_backend(scheme):
     """A store configured with backend="pallas" (interpret mode on CPU)
     reconciles exactly like the numpy reference."""

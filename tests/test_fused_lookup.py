@@ -16,10 +16,9 @@ from repro.core.engine import NumpyBackend, PallasBackend
 from repro.core.engine.backend import assign_bounds
 from repro.core.lsm.sstable import partition_run, reset_sst_ids
 from repro.core.lsm.storage import LSMStore, StoreConfig
-from repro.core.service import (MemoryGovernor, MemoryPlan, Get, Put,
-                                StorageService)
+from repro.core.service import (DevicePoolGovernor, MemoryGovernor,
+                                MemoryPlan, Get, Put, StorageService)
 from repro.core.shard import ShardedStore
-from repro.runtime.hbm_tuner import DevicePoolGovernor
 
 KB, MB = 1 << 10, 1 << 20
 

@@ -13,7 +13,6 @@ invalidating a live page.
 from types import SimpleNamespace
 
 import numpy as np
-import pytest
 
 from repro.core.lsm.sstable import reset_sst_ids
 from repro.core.lsm.storage import LSMStore, StoreConfig

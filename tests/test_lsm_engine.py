@@ -7,7 +7,7 @@ import pytest
 from repro.core.lsm.grouped_l0 import GroupedL0
 from repro.core.lsm.levels import DiskLevels
 from repro.core.lsm.sstable import merge_runs, sstable_from_run
-from repro.core.lsm.storage import LSMStore, StoreConfig
+from repro.core.lsm.storage import SCHEMES, LSMStore, StoreConfig
 
 KB = 1 << 10
 MB = 1 << 20
@@ -114,9 +114,7 @@ def small_config(**kw):
     return StoreConfig(**base)
 
 
-@pytest.mark.parametrize("scheme", ["partitioned", "btree-dynamic",
-                                    "btree-static", "accordion-index",
-                                    "accordion-data"])
+@pytest.mark.parametrize("scheme", SCHEMES)
 def test_store_reconciliation_oracle(scheme):
     rng = np.random.default_rng(42)
     store = LSMStore(small_config(scheme=scheme, write_memory_bytes=2 * MB,

@@ -33,7 +33,7 @@ from repro.core.service.governor import MemoryPlan, StallGovernor
 from repro.core.shard import ShardedStore
 from repro.runtime.latency import LatencyHistogram
 
-from test_differential import KB, MB
+from test_differential import KB
 from test_recovery import exact_counters, sharded_fingerprint
 from test_scheduler_interleave import (TREES, build, gen_schedule,
                                        run_schedule, small_config, state_of)
