@@ -234,6 +234,11 @@ class ExecutionBackend:
         """Merge sorted (keys, vals) runs, ordered newest-first, into one
         sorted unique run with newest-wins reconciliation.
 
+        Callers may pass one run per table: the Pallas backend's fold
+        merges each group of consecutive key-disjoint runs (a level's
+        tables) into the accumulated run once, piecewise, rather than
+        once per run (``kernels.merge.ops.merge_runs_device``).
+
         Returns (keys, vals) as int64 numpy arrays.
         """
         raise NotImplementedError

@@ -8,6 +8,7 @@ newer run); the global keep-mask drops duplicate keys (reconciliation).
 """
 from __future__ import annotations
 
+import bisect
 from functools import partial
 
 import jax
@@ -295,28 +296,100 @@ def run_store_probe(fslots, keys, vals, operands, n, *, steps: int,
             win[:n].astype(np.int64))
 
 
+def _disjoint_groups(runs):
+    """Maximal groups of consecutive ``runs`` (kept in their order) whose
+    [first, last] key ranges intersect none of the group's others, each
+    group's runs sorted by first key: a group holds no key twice, so it
+    reads as one sorted run."""
+    groups = []
+    for k, v in runs:
+        lo, hi = int(k[0]), int(k[-1])
+        if groups:
+            firsts, lasts, members = groups[-1]
+            p = bisect.bisect_left(firsts, lo)
+            overlaps = ((p > 0 and lasts[p - 1] >= lo)
+                        or (p < len(firsts) and firsts[p] <= hi))
+            if not overlaps:
+                firsts.insert(p, lo)
+                lasts.insert(p, hi)
+                members.insert(p, (k, v))
+                continue
+        groups.append(([lo], [hi], [(k, v)]))
+    return [g[2] for g in groups]
+
+
+def _merge_group(ka, va, group, **kw):
+    """Merge the run ``(ka, va)`` (newer) with one key-disjoint ``group``
+    of tables sorted by first key, piecewise: table j meets the slice of
+    the run from its first key to the next table's (the first slice also
+    takes what lies below table 0, the last what lies above the last
+    table), so concatenating the pieces' merges in table order gives the
+    merged run. A table with an empty slice passes through. Every piece
+    is dispatched before any result is pulled; one keep-index compacts
+    them all. Returns the merged run and the device merges dispatched."""
+    cuts = np.searchsorted(ka, [int(k[0]) for k, _ in group[1:]])
+    cuts = np.concatenate([[0], cuts, [ka.shape[0]]])
+    outs, steps = [], 0
+    for (kb, vb), a0, a1 in zip(group, cuts[:-1], cuts[1:]):
+        if a0 == a1:
+            outs.append((kb, vb, None))
+            continue
+        ka_p, va_p = _pad_run(ka[a0:a1], va[a0:a1], next_pow2(a1 - a0))
+        kb_p, vb_p = _pad_run(kb, vb, next_pow2(kb.shape[0]))
+        outs.append(merge_sorted_runs(*(to_device(x, jnp.int32) for x in
+                                        (ka_p, va_p, kb_p, vb_p)), **kw))
+        steps += 1
+    keys, vals, keep = [], [], []
+    for k, v, m in outs:
+        if m is None:
+            m = np.ones(k.shape[0], bool)
+        else:
+            k, v, m = to_host(k), to_host(v), to_host(m)
+        keys.append(k)
+        vals.append(v)
+        keep.append(m)
+    keep = np.concatenate(keep)
+    return np.concatenate(keys)[keep], np.concatenate(vals)[keep], steps
+
+
 def merge_runs_device(runs, *, tile: int = MERGE_TILE, use_kernel: bool = True,
                       interpret: bool = True):
     """Run-sized engine entry point: fold k sorted runs (ordered newest
     first, keys in [0, INT_MAX)) into one deduped run with newest-wins
     reconciliation.
 
+    Callers may pass one run per table. Consecutive runs whose key
+    ranges are disjoint form one group (``_disjoint_groups``), which
+    holds no key twice and so merges as one run: the first group,
+    concatenated, is the accumulated run, and each later, older group is
+    merged into it once, piecewise (``_merge_group``: each table with
+    the accumulated run's slice over its key range), instead of once
+    per table. Where runs overlap pairwise, every group is one run and
+    its one piece the whole accumulated run: the plain pairwise fold.
+    The ``merge.fold`` span counts ``merge.groups`` and ``merge.steps``,
+    one per device merge dispatched.
+
     Each operand is padded to a power-of-two length with INT_MAX sentinels
     -- dropped by the kernel's keep-mask -- so the jitted tile composition
     compiles once per size bucket rather than once per exact run length.
+    A piece is a slice of the accumulated run and its partner one input
+    run, so the padded sizes stay those of the pairwise fold.
     Returns dense int32 numpy (keys, vals).
     """
     rs = [(np.asarray(k, np.int32), np.asarray(v, np.int32))
           for k, v in runs if len(k)]
     if not rs:
         return np.empty(0, np.int32), np.empty(0, np.int32)
-    ka, va = rs[0]
     with tracing.span("merge.fold"):
-        tracing.count("merge.steps", len(rs) - 1)
-        for kb, vb in rs[1:]:
-            ka_p, va_p = _pad_run(ka, va, next_pow2(ka.shape[0]))
-            kb_p, vb_p = _pad_run(kb, vb, next_pow2(kb.shape[0]))
-            ka, va = merge_runs_dedup(ka_p, va_p, kb_p, vb_p, tile=tile,
-                                      use_kernel=use_kernel,
-                                      interpret=interpret)
+        groups = _disjoint_groups(rs)
+        tracing.count("merge.groups", len(groups))
+        ka = np.concatenate([k for k, _ in groups[0]])
+        va = np.concatenate([v for _, v in groups[0]])
+        steps = 0
+        for group in groups[1:]:
+            ka, va, n = _merge_group(ka, va, group, tile=tile,
+                                     use_kernel=use_kernel,
+                                     interpret=interpret)
+            steps += n
+        tracing.count("merge.steps", steps)
     return ka, va

@@ -152,6 +152,26 @@ def test_a_submit_records_every_span(backend):
             == c["merge.steps"]
 
 
+def test_merge_folds_count_their_groups():
+    """``merge.groups`` sums over the ``merge.fold`` records, as
+    ``merge.steps`` does; a fold of g key-disjoint groups dispatches at
+    least g - 1 device merges, and none with one group."""
+    svc = _service("pallas")
+    with tracing.recording():
+        _drive(svc)
+    folds = [r for r in tracing.records() if r.name == "merge.fold"]
+    c = tracing.counters()
+    assert folds and c["merge.groups"] > 0
+    assert sum(r.counts["merge.groups"] for r in folds) == c["merge.groups"]
+    assert sum(r.counts["merge.steps"] for r in folds) == c["merge.steps"]
+    for r in folds:
+        groups, steps = r.counts["merge.groups"], r.counts["merge.steps"]
+        assert groups >= 1 and steps >= groups - 1
+        assert (steps == 0) == (groups == 1)
+    # the mechanism engages: some group of several tables merges in pieces
+    assert c["merge.groups"] < c["merge.steps"] + len(folds)
+
+
 @pytest.mark.parametrize("backend", ["numpy", "pallas"])
 def test_memory_levels_build_their_views_once_per_change(backend):
     from repro.core.engine import get_backend
